@@ -6,6 +6,8 @@ processes."""
 from __future__ import annotations
 
 import json
+import subprocess
+import sys
 import threading
 import time
 
@@ -113,6 +115,13 @@ class TestBootSequence:
             "/run/vpp-tpu/io-ctl.sock"
         assert f"63:afpacket:eth9" in io_argv
         sup.stop()
+        # init and the IO daemon never import JAX: only the agent they
+        # spawn may hold the chip
+        probe = ("import sys, vpp_tpu.cmd.init_main, "
+                 "vpp_tpu.cmd.io_daemon; print('jax' in sys.modules)")
+        out = subprocess.run([sys.executable, "-c", probe], check=True,
+                             capture_output=True, text=True).stdout
+        assert out.strip() == "False"
 
     def test_plan_timeout_is_an_error(self, tmp_path):
         cfg = cfg_with_io(tmp_path)

@@ -235,6 +235,36 @@ def test_lpm_matches_oracle_and_dense(seed, n_routes, fib_slots):
     assert_fib_equal(fib_lookup_dense(t, pkts), oracle)
 
 
+def test_full_hint_bucket_finds_every_prefix(monkeypatch):
+    """A stride-hint bucket holding as many prefixes as it can (every
+    /24 under one top-6-bit prefix): the bounded bisection still lands
+    on each one. One step short, the last candidate of a full bucket
+    fell through to the default route."""
+    monkeypatch.setenv("VPPT_LPM_HINT_MIN", "64")
+    b = TableBuilder(_cfg(fib_slots=128, fib_impl="lpm",
+                          fib_lpm_plen_caps=(1,) + (0,) * 23 + (64,)))
+    b.add_route("0.0.0.0/0", 1, Disposition.REMOTE, node_id=-1, slot=0)
+    for i in range(64):
+        b.add_route(f"10.0.{i}.0/24", 2, Disposition.REMOTE,
+                    node_id=i, slot=1 + i)
+    t = b.to_device()
+    assert t.fib_lpm_hint.shape[0] > 0  # the hint layer is engaged
+    n = 64 * 4
+    dst = np.array([(10 << 24) | (i // 4 << 8) | (i % 4 * 60 + 1)
+                    for i in range(n)], np.uint32)
+    pkts = PacketVector(
+        src_ip=jnp.zeros((n,), jnp.uint32), dst_ip=jnp.asarray(dst),
+        proto=jnp.full((n,), 6, jnp.int32),
+        sport=jnp.zeros((n,), jnp.int32), dport=jnp.zeros((n,), jnp.int32),
+        ttl=jnp.full((n,), 64, jnp.int32),
+        pkt_len=jnp.full((n,), 64, jnp.int32),
+        rx_if=jnp.zeros((n,), jnp.int32),
+        flags=jnp.full((n,), FLAG_VALID, jnp.int32))
+    oracle = NumpyLpmOracle(b).lookup(pkts)
+    assert (oracle["node_id"] == np.arange(n) // 4).all()
+    assert_fib_equal(fib_lookup_lpm(t, pkts), oracle)
+
+
 def test_default_host_and_overlapping_covers():
     """/0 default + nested /8 /16 /24 /32 covers of one address:
     longest populated length wins at every nesting step, and deleting

@@ -565,11 +565,7 @@ class TestShardComposition:
         from jax.sharding import PartitionSpec as P
 
         from vpp_tpu.ops.session import session_lookup_reverse_idx
-        from vpp_tpu.parallel.partition import (
-            RULE_AXIS,
-            ShardCtx,
-            shard_map,
-        )
+        from vpp_tpu.parallel.partition import RULE_AXIS, ShardCtx
 
         dp, up, pod = build_dp(tenants=[
             {"id": 1, "prefixes": [T1_NET], "sess_buckets": 8},
@@ -611,7 +607,7 @@ class TestShardComposition:
                 tbl, pv, jnp.int32(2), shard=ctx, tnt=True)
 
         with mesh:
-            sharded = shard_map(
+            sharded = jax.shard_map(
                 kernel, mesh=mesh,
                 in_specs=(tbl_specs, P()),
                 out_specs=(P(), P()),

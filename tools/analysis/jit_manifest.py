@@ -61,9 +61,9 @@ JIT_SITES = {
         "static interpret flag only — the fused rung gathers segment "
         "rows on-device and reduces them in VMEM tiles",
     ("vpp_tpu/ops/lpm.py", "@lpm_fused_lookup"):
-        "pallas LPM binary-search kernel entry (ISSUE 16): one grid "
-        "fused over the populated length planes, longest-first "
-        "first-hit-wins accumulation; static interpret flag only",
+        "pallas LPM kernel entry (ISSUE 16): VMEM-resident planes, "
+        "a compare-scan of each populated length's live entries, "
+        "longest-first first hit wins; static interpret flag only",
     ("vpp_tpu/ops/session.py", "@sess_probe_ways"):
         "pallas session bucket-probe kernel entry (ISSUE 16): whole "
         "key columns staged to VMEM, per-packet way election in-core; "

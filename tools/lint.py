@@ -74,7 +74,8 @@ from analysis.uploadlint import uploads_lint  # noqa: E402
 from analysis.transferlint import transfers_lint  # noqa: E402
 from analysis.donatelint import donate_lint  # noqa: E402
 
-ROOTS = ("vpp_tpu", "tests", "bench.py", "__graft_entry__.py", "tools")
+ROOTS = ("vpp_tpu", "tests", "bench.py", "chip_smoke.py",
+         "__graft_entry__.py", "tools")
 
 
 def lint_file(path: Path) -> list:

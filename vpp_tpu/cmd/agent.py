@@ -1013,6 +1013,7 @@ def main(argv=None) -> int:
     import argparse
 
     from vpp_tpu.cmd.config import load_config
+    from vpp_tpu.compile_cache import enable_compile_cache
 
     parser = argparse.ArgumentParser(prog="vpp-tpu-agent")
     parser.add_argument("--config", default=None, help="agent YAML config")
@@ -1021,6 +1022,7 @@ def main(argv=None) -> int:
         level=logging.INFO,
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
+    enable_compile_cache()
     agent = ContivAgent(load_config(args.config))
     stop = threading.Event()
     for sig in (signal.SIGTERM, signal.SIGINT):

@@ -12,7 +12,7 @@ import dataclasses
 from typing import Optional
 
 from vpp_tpu.ipam.ipam import IpamConfig
-from vpp_tpu.pipeline.tables import DataplaneConfig
+from vpp_tpu.pipeline.config import DataplaneConfig
 
 
 @dataclasses.dataclass
@@ -31,9 +31,7 @@ class IOConfig:
     control_socket: str = ""
     # pump tuning (io/pump.py): coalesced device batch cap, in-flight
     # batches before the dispatch stage backpressures, concurrent
-    # result fetchers (None = auto: 8 on a remote device so fetch RPC
-    # round trips overlap, 1 on the CPU backend where extra blocked
-    # threads only churn the GIL). ``depth``/``workers`` are the
+    # result fetchers (None = 1). ``depth``/``workers`` are the
     # legacy aliases of ``max_inflight``/``fetch_workers`` — the new
     # names win when both are set.
     max_batch: int = 2048
@@ -328,7 +326,7 @@ class AgentConfig:
 
         build_section("dataplane", DataplaneConfig, set(DataplaneConfig._fields))
         if "dataplane" in d:
-            from vpp_tpu.pipeline.tables import validate_dataplane_config
+            from vpp_tpu.pipeline.config import validate_dataplane_config
 
             validate_dataplane_config(d["dataplane"])
         if d.get("tenants"):

@@ -31,6 +31,7 @@ def main(argv=None) -> int:
     import argparse
 
     from vpp_tpu.cmd.config import load_config
+    from vpp_tpu.compile_cache import enable_compile_cache
     from vpp_tpu.parallel.runtime import MeshRuntime
 
     parser = argparse.ArgumentParser(prog="vpp-tpu-mesh-agent")
@@ -52,6 +53,7 @@ def main(argv=None) -> int:
         format="%(asctime)s %(name)s %(levelname)s %(message)s",
     )
     config = load_config(args.config)
+    enable_compile_cache()
     rule_shards = (
         args.rule_shards if args.rule_shards is not None
         else config.mesh.rule_shards

@@ -7,17 +7,27 @@ Reference analogs: the contiv plugin's remoteCNIserver
 sandbox; the shim forwards Add/Delete to the node agent's CNI server,
 which allocates an IP (IPAM), wires a dataplane interface + route, and
 persists the container config for restart resync.
+
+The names below load on first use: the IO daemon's control server
+imports ``vpp_tpu.cni.transport`` and must stay JAX-free.
 """
 
-from vpp_tpu.cni.containeridx import ContainerConfig, ContainerIndex
-from vpp_tpu.cni.model import CNIReply, CNIRequest, ResultCode
-from vpp_tpu.cni.server import RemoteCNIServer
+import importlib
 
-__all__ = [
-    "CNIReply",
-    "CNIRequest",
-    "ContainerConfig",
-    "ContainerIndex",
-    "RemoteCNIServer",
-    "ResultCode",
-]
+_EXPORTS = {
+    "CNIReply": "model",
+    "CNIRequest": "model",
+    "ContainerConfig": "containeridx",
+    "ContainerIndex": "containeridx",
+    "RemoteCNIServer": "server",
+    "ResultCode": "model",
+}
+
+__all__ = list(_EXPORTS)
+
+
+def __getattr__(name):
+    if name not in _EXPORTS:
+        raise AttributeError(name)
+    return getattr(importlib.import_module(f"vpp_tpu.cni.{_EXPORTS[name]}"),
+                   name)

@@ -14,16 +14,16 @@ from __future__ import annotations
 import os
 from typing import Dict, Optional
 
-from vpp_tpu.native.ring import _BUILD_DIR, build_native
+from vpp_tpu.native.ring import build_native, native_lib_path
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                     "native", "vcl_preload.c")
-_LIB = os.path.join(_BUILD_DIR, "libvclshim.so")
 
 
 def shim_path(force: bool = False) -> str:
-    """Compile-if-stale; returns the absolute libvclshim.so path."""
-    return build_native(_SRC, _LIB, force)
+    """Compile-if-missing; returns the absolute path of the shim,
+    ``libvclshim-<sha>.so`` keyed by the source content."""
+    return build_native(_SRC, native_lib_path(_SRC, "libvclshim"), force)
 
 
 def vcl_env(

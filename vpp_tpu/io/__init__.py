@@ -9,21 +9,31 @@ jitted TPU pipeline, and back out rewritten.
   wire -> Transport.recv -> PacketCodec.parse -> rx IORing
        -> DataplanePump -> Dataplane.process (TPU) -> tx IORing
        -> PacketCodec.rewrite (+ VXLAN encap) -> Transport.send -> wire
+
+The names below load on first use: the IO daemon imports this package
+and must stay JAX-free, while ``DataplanePump`` drives the device.
 """
 
-from vpp_tpu.io.rings import IORing, IORingPair
-from vpp_tpu.io.transport import (
-    AfPacketTransport,
-    SocketPairTransport,
-    TapTransport,
-    Transport,
-)
-from vpp_tpu.io.daemon import IODaemon
-from vpp_tpu.io.governor import LatencyGovernor, PriorityFilter
-from vpp_tpu.io.pump import DataplanePump
+import importlib
 
-__all__ = [
-    "IORing", "IORingPair", "Transport", "AfPacketTransport",
-    "TapTransport", "SocketPairTransport", "IODaemon", "DataplanePump",
-    "LatencyGovernor", "PriorityFilter",
-]
+_EXPORTS = {
+    "IORing": "rings",
+    "IORingPair": "rings",
+    "Transport": "transport",
+    "AfPacketTransport": "transport",
+    "TapTransport": "transport",
+    "SocketPairTransport": "transport",
+    "IODaemon": "daemon",
+    "DataplanePump": "pump",
+    "LatencyGovernor": "governor",
+    "PriorityFilter": "governor",
+}
+
+__all__ = list(_EXPORTS)
+
+
+def __getattr__(name):
+    if name not in _EXPORTS:
+        raise AttributeError(name)
+    return getattr(importlib.import_module(f"vpp_tpu.io.{_EXPORTS[name]}"),
+                   name)

@@ -16,18 +16,9 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
-from vpp_tpu.native.ring import RING_COLUMNS, load_native
+from vpp_tpu.native.ring import RING_COLUMNS, load_native, native_lib_path
 
-_PKG_DIR = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_PKG_DIR, "pkt_io.cpp")
-_BUILD_DIR = (
-    os.path.join(_PKG_DIR, "build")
-    if os.access(_PKG_DIR, os.W_OK)
-    else os.path.join(
-        os.environ.get("TMPDIR", "/tmp"), f"vpp_tpu_native_{os.getuid()}"
-    )
-)
-_LIB = os.path.join(_BUILD_DIR, "libpktio.so")
+_SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)), "pkt_io.cpp")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -59,7 +50,7 @@ def _load() -> ctypes.CDLL:
     with _lock:
         if _lib is not None:
             return _lib
-        lib = load_native(_SRC, _LIB)
+        lib = load_native(_SRC, native_lib_path(_SRC, "libpktio"))
         lib.pio_vec.restype = ctypes.c_uint32
         lib.pio_columns.restype = ctypes.c_uint32
         lib.pio_parse.restype = ctypes.c_uint32

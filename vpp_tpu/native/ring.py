@@ -7,13 +7,15 @@ Column order MUST match vpp_tpu.pipeline.vector.PacketVector's fields —
 a committed slot is viewed as nine numpy arrays, zero-copy, and can be
 lifted into a PacketVector for the jitted pipeline step.
 
-Build: compiled on demand with g++ into native/build/libframering.so
-(cached; rebuilt when the source is newer).
+Build: compiled on demand with g++ into
+native/build/libframering-<sha>.so, keyed by the source's content: a
+build directory copied from elsewhere never stands in for the source.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -53,28 +55,34 @@ _BUILD_DIR = (
         os.environ.get("TMPDIR", "/tmp"), f"vpp_tpu_native_{os.getuid()}"
     )
 )
-_LIB = os.path.join(_BUILD_DIR, "libframering.so")
+_CXX = ("g++", "-std=c++17", "-O2", "-shared", "-fPIC")
 
 _build_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 
 
+def native_lib_path(src: str, stem: str) -> str:
+    """``<build>/<stem>-<sha>.so``: the hash covers the source bytes
+    and the compile command, so an edit (or a foreign build directory)
+    can never be loaded in place of the tracked source."""
+    h = hashlib.sha256(" ".join(_CXX).encode())
+    with open(src, "rb") as f:
+        h.update(f.read())
+    return os.path.join(_BUILD_DIR, f"{stem}-{h.hexdigest()[:16]}.so")
+
+
 def build_native(src: str, lib: str, force: bool = False) -> str:
-    """Compile one native source if missing/stale; returns the .so path."""
+    """Compile one native source unless its content-keyed ``lib``
+    (``native_lib_path``) exists; returns the .so path."""
     with _build_lock:
-        if (
-            not force
-            and os.path.exists(lib)
-            and os.path.getmtime(lib) >= os.path.getmtime(src)
-        ):
+        if not force and os.path.exists(lib):
             return lib
         os.makedirs(os.path.dirname(lib), exist_ok=True)
         # per-process tmp name: concurrent builds from separate processes
         # must not clobber each other's output mid-write
         tmp = f"{lib}.tmp.{os.getpid()}.so"
         proc = subprocess.run(
-            ["g++", "-std=c++17", "-O2", "-shared", "-fPIC", "-o", tmp, src],
-            capture_output=True, text=True,
+            [*_CXX, "-o", tmp, src], capture_output=True, text=True,
         )
         if proc.returncode != 0:
             raise RuntimeError(
@@ -86,15 +94,15 @@ def build_native(src: str, lib: str, force: bool = False) -> str:
 
 
 def build_library(force: bool = False) -> str:
-    """Compile the ring library if missing/stale; returns the .so path."""
-    return build_native(_SRC, _LIB, force)
+    """Compile the ring library if missing; returns the .so path."""
+    return build_native(_SRC, native_lib_path(_SRC, "libframering"), force)
 
 
 def load_native(src: str, lib_path: str) -> ctypes.CDLL:
-    """Build-if-stale then dlopen, with a rebuild fallback: a cached .so
-    from another arch/libc (copied build dir, container image change)
-    passes the mtime check but fails to load — force a recompile from
-    source instead of surfacing the dlopen error."""
+    """Build-if-missing then dlopen, with a rebuild fallback: a cached
+    .so from another arch/libc (container image change) carries the
+    right name but fails to load — force a recompile from source
+    instead of surfacing the dlopen error."""
     path = build_native(src, lib_path)
     try:
         return ctypes.CDLL(path)
@@ -106,10 +114,10 @@ def _load() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    # build_library no-ops when the cached .so is fresh, and rebuilds on
-    # source changes — loading a stale binary would silently run old
-    # slot-layout semantics against peers built from the new source
-    lib = load_native(_SRC, _LIB)
+    # the library name is keyed by the source content — loading a stale
+    # binary would silently run old slot-layout semantics against peers
+    # built from the new source
+    lib = load_native(_SRC, native_lib_path(_SRC, "libframering"))
     lib.fr_required_size.restype = ctypes.c_uint64
     lib.fr_required_size.argtypes = [ctypes.c_uint32]
     for fn in ("fr_slot_size", "fr_vec", "fr_columns", "fr_header_size",

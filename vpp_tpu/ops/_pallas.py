@@ -15,7 +15,8 @@ modules can never disagree about when the compiled kernel serves:
   inside a jit trace.
 - ``use_pallas()``: the dispatch predicate — run the compiled kernel
   only on a real TPU backend; everywhere else (CPU harness, tests,
-  meshes of virtual devices) the jnp reference serves. Pallas
+  meshes of virtual devices) the jnp reference serves. On a TPU
+  backend an unimportable Pallas is an error. Pallas
   *interpret* mode stays reachable for the differential suites by
   passing ``interpret=True`` to the kernel entry points directly.
 
@@ -61,12 +62,31 @@ def get_pallas(caller: str = "pallas kernel"):
     return pl, pltpu
 
 
+def out_struct(shape, dtype, *inputs):
+    """A ``pallas_call`` output shape that varies over every mesh axis
+    its inputs vary over: under ``shard_map`` (the rule-sharded MXU
+    classify) JAX requires the kernel to declare it; elsewhere the set
+    is empty."""
+    import jax
+
+    vma = frozenset().union(*(jax.typeof(x).vma for x in inputs))
+    return jax.ShapeDtypeStruct(shape, dtype, vma=vma or None)
+
+
 def use_pallas() -> bool:
     """The ONE backend-dispatch predicate shared by all kernel modules:
     compiled Pallas kernels serve on a real TPU backend only. CPU (and
     anything else) takes the bit-exact jnp reference rung — interpret
     mode is for the differential suites, not production dispatch (it
-    is orders of magnitude slower than the jnp rung on CPU)."""
+    is orders of magnitude slower than the jnp rung on CPU). A TPU
+    backend without an importable Pallas is a broken installation,
+    not a reason to serve the jnp rung quietly: it raises."""
     import jax
 
-    return jax.default_backend() == "tpu" and pallas_available()
+    if jax.default_backend() != "tpu":
+        return False
+    if not pallas_available():
+        raise RuntimeError(
+            "TPU backend without an importable jax.experimental.pallas "
+            "(and its tpu module): the installation is incomplete")
+    return True

@@ -37,7 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from vpp_tpu.ops._pallas import get_pallas
+from vpp_tpu.ops._pallas import get_pallas, out_struct
 from vpp_tpu.ops.acl import AclVerdict, assemble_global_verdict
 from vpp_tpu.pipeline.vector import PacketVector
 
@@ -284,7 +284,7 @@ def mxu_first_match(
         ],
         out_specs=pl.BlockSpec((pt, 1), lambda i, j: (i, 0),
                                memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((p_pad, 1), jnp.int32),
+        out_shape=out_struct((p_pad, 1), jnp.int32, bits, coeff, k),
         interpret=interpret,
         cost_estimate=pl.CostEstimate(
             flops=2 * p_pad * PLANES * r_pad,

@@ -137,7 +137,10 @@ def lpm_hint_layout(
             continue
         b = min(length, LPM_HINT_BITS, max(1, (cap - 1).bit_length()))
         bucket = min(cap, 1 << (length - b))
-        rows.append((b, off, (bucket - 1).bit_length()))
+        # a lower-bound bisection over n entries has n + 1 outcomes:
+        # bit_length(n) steps, one more than bit_length(n - 1) when a
+        # bucket is full
+        rows.append((b, off, bucket.bit_length()))
         off += (1 << b) + 1
     return tuple(rows), off
 
@@ -295,22 +298,38 @@ def fib_lookup_lpm(tables, pkts):
 # --- pallas rung (ISSUE 16) -------------------------------------------
 #
 # The fib_impl ladder's "pallas" rung: the per-length searches above
-# unroll into 33 separate searchsorted/gather chains — each one streams
-# the query vector and its plane through HBM independently, and XLA
-# cannot fuse across them because every chain ends in a gather. The
-# fused kernel stacks the populated planes into ONE [L, Npad] VMEM-
-# resident matrix and walks all lengths for a packet tile in a single
-# pallas_call: the queries load once, the bisection runs on registers,
-# and the longest-first first-hit fold happens in VMEM instead of L
-# round trips through ``jnp.where``. Same dispatch discipline as the
-# other kernels (ops/_pallas.py): compiled on a real TPU backend, the
-# trace-time-unrolled rung above everywhere else, interpret mode for
-# the differential suite.
+# unroll into separate searchsorted/gather chains — each one streams
+# the query vector and its plane through HBM independently. The fused
+# kernel holds the populated planes VMEM-resident as [L, R, 128] tiles
+# and, for a packet tile, scans every length's LIVE entries with a
+# compare-and-min over (8, 128) prefix tiles: the masked query column
+# is broadcast across lanes, so no step gathers (Mosaic lowers no
+# per-packet gather out of a vector). The scan is linear in the live
+# routes, which is why the rung is gated to planes that fit
+# ``LPM_PALLAS_VMEM_BUDGET``; larger FIBs keep the bisection rung.
+# Same dispatch discipline as the other kernels (ops/_pallas.py):
+# compiled on a real TPU backend, the walk above everywhere else,
+# interpret mode for the differential suite.
 
-# packet-tile rows per grid step
-_LPM_PT = 256
-# plane pad columns round to the TPU lane width
-_LPM_LANES = 128
+# packet rows per grid step, scanned in sub-tiles of 128 rows
+_LPM_PT = 1024
+_LPM_SUB = 128
+# plane entries per scan step: one (8, 128) int32 tile
+_LPM_CHUNK = 8 * 128
+# VMEM budget of the stacked prefix + slot planes (2 x 4 bytes per
+# padded entry per populated length)
+LPM_PALLAS_VMEM_BUDGET = 4 << 20
+_SLOT_MISS = 0x7FFFFFFF
+
+
+def lpm_pallas_fits(config) -> bool:
+    """Whether the populated planes, stacked at the widest one's padded
+    width, fit the pallas rung's VMEM budget."""
+    caps = [c for c in lpm_len_caps(config) if c > 0]
+    if not caps:
+        return False
+    npad = -(-max(caps) // _LPM_CHUNK) * _LPM_CHUNK
+    return 2 * 4 * len(caps) * npad <= LPM_PALLAS_VMEM_BUDGET
 
 
 def _lpm_bias(x: jnp.ndarray) -> jnp.ndarray:
@@ -321,134 +340,141 @@ def _lpm_bias(x: jnp.ndarray) -> jnp.ndarray:
         x ^ jnp.uint32(0x80000000), jnp.int32)
 
 
-def _lpm_search_kernel(m_ref, cnt_ref, pfx_ref, slot_ref,
-                       found_ref, out_ref, *, steps: int):
-    """One (packet-tile, length) grid step: bisect this length's
-    sorted plane for the tile's masked queries and fold the hit into
-    the running longest-first winner (grid iterates the length axis
-    innermost, so the out blocks accumulate across lengths — the
-    acl_mxu rule-tile pattern)."""
+def _lpm_scan_kernel(mask_ref, cnt_ref, dst_ref, pfx_ref, slot_ref,
+                     out_ref, *, nl: int):
+    """One packet tile: for each 128-row sub-tile and each length
+    (longest first), min-reduce the slots of the live entries whose
+    prefix equals the masked query; the first length with a hit wins.
+    Entries past a length's live count carry ``_SLOT_MISS`` (set by
+    the wrapper), so a scan rounded up to whole tiles stays exact."""
     from vpp_tpu.ops._pallas import get_pallas
 
     pl, _pltpu = get_pallas("lpm_fused_lookup")
-    l = pl.program_id(1)
-    m = m_ref[...][:, 0]          # [pt] biased masked queries
-    pfx = pfx_ref[...][0]         # [Npad] biased sorted prefixes
-    slots = slot_ref[...][0]      # [Npad] owning FIB slots
-    n = cnt_ref[0, 0]             # live entries of this length
-    top = pfx.shape[0] - 1
-    # bisect_left over the live region [0, n): identical insertion
-    # index to the flat searchsorted over the padded plane (pads sort
-    # at/after every real value; the i < n guard below rejects the
-    # pad region exactly like the ``i < cnt[L]`` guard in
-    # fib_lookup_lpm), with the step count static from the SHAPE.
-    lo = jnp.zeros(m.shape, jnp.int32)
-    hi = jnp.broadcast_to(n, m.shape).astype(jnp.int32)
-    for _ in range(steps):
-        mid = (lo + hi) >> 1
-        p = pfx[jnp.clip(mid, 0, top)]
-        less = p < m
-        active = lo < hi
-        lo = jnp.where(active & less, mid + 1, lo)
-        hi = jnp.where(active & ~less, mid, hi)
-    ic = jnp.clip(lo, 0, top)
-    hit = (pfx[ic] == m) & (lo < n)
-    s = jnp.where(hit, slots[ic], 0)
+    sub = _LPM_SUB
+    sign = jnp.int32(-(1 << 31))
 
-    @pl.when(l == 0)
-    def _():
-        found_ref[...] = hit[:, None].astype(jnp.int32)
-        out_ref[...] = s[:, None]
+    def rows(r, carry):
+        start = pl.multiple_of(r * sub, sub)
+        d = dst_ref[pl.ds(start, sub), :]          # [sub, 1]
+        best = jnp.full((sub, 1), _SLOT_MISS, jnp.int32)
+        for l in range(nl):
+            m = jnp.broadcast_to((d & mask_ref[l]) ^ sign, (sub, 128))
 
-    @pl.when(l > 0)
-    def _():
-        prev = found_ref[...][:, 0] != 0
-        take = hit & ~prev
-        out_ref[...] = jnp.where(take, s, out_ref[...][:, 0])[:, None]
-        found_ref[...] = (prev | hit)[:, None].astype(jnp.int32)
+            def scan(c, acc, l=l, m=m):
+                c8 = pl.multiple_of(c * 8, 8)
+                pf = pfx_ref[l, pl.ds(c8, 8), :]
+                sl = slot_ref[l, pl.ds(c8, 8), :]
+                for k in range(8):
+                    acc = jnp.minimum(acc, jnp.where(
+                        m == pf[k:k + 1, :], sl[k:k + 1, :], _SLOT_MISS))
+                return acc
+
+            n = (cnt_ref[l] + _LPM_CHUNK - 1) // _LPM_CHUNK
+            acc = lax.fori_loop(
+                0, n, scan, jnp.full((sub, 128), _SLOT_MISS, jnp.int32))
+            hit = jnp.min(acc, axis=1, keepdims=True)
+            best = jnp.where(best != _SLOT_MISS, best, hit)
+        out_ref[pl.ds(start, sub), :] = best
+        return carry
+
+    lax.fori_loop(0, out_ref.shape[0] // sub, rows, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def lpm_fused_lookup(m_cols: jnp.ndarray, cnt_stack: jnp.ndarray,
-                     pfx_stack: jnp.ndarray, slot_stack: jnp.ndarray,
-                     interpret: bool = False):
+def lpm_fused_lookup(dst: jnp.ndarray, masks: jnp.ndarray,
+                     cnt: jnp.ndarray, pfx_stack: jnp.ndarray,
+                     slot_stack: jnp.ndarray, interpret: bool = False):
     """Fused all-lengths LPM search.
 
-    m_cols [P, L] int32: per-length masked queries, already biased
-    (``_lpm_bias``), length axis LONGEST FIRST — the first hit along
-    it is the longest match. cnt_stack [L, 1] int32 live counts,
-    pfx_stack [L, Npad] int32 biased sorted prefixes (pad int32 max),
-    slot_stack [L, Npad] int32 owning slots. Returns (found [P] bool,
-    slot [P] int32, 0 when miss) — bit-exact with the trace-time-
-    unrolled walk in ``fib_lookup_lpm`` over the same planes
-    (tests/test_pallas_kernels.py holds them together)."""
-    p, nl = m_cols.shape
-    npad = pfx_stack.shape[1]
-    pt = min(_LPM_PT, max(8, p))
-    p_pad = ((p + pt - 1) // pt) * pt
-    if p_pad != p:
-        m_cols = jnp.pad(m_cols, ((0, p_pad - p), (0, 0)))
-    steps = max(1, npad).bit_length()
-    kernel = functools.partial(_lpm_search_kernel, steps=steps)
-
+    dst [P] uint32 destinations; masks [L] uint32 the prefix masks of
+    the stacked lengths, LONGEST FIRST — the first hit along them is
+    the longest match; cnt [L] int32 live counts; pfx_stack [L, Npad]
+    int32 biased (``_lpm_bias``) sorted prefixes; slot_stack [L, Npad]
+    int32 owning slots. Returns (found [P] bool, slot [P] int32, 0 when
+    miss) — bit-exact with ``lpm_fused_reference`` and the walk in
+    ``fib_lookup_lpm`` over the same planes (tests/test_pallas_kernels.py
+    holds them together)."""
     from vpp_tpu.ops._pallas import get_pallas
 
     pl, pltpu = get_pallas("lpm_fused_lookup")
-    found, slot = pl.pallas_call(
-        kernel,
-        grid=(p_pad // pt, nl),
-        in_specs=[
-            pl.BlockSpec((pt, 1), lambda i, l: (i, l),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i, l: (l, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, npad), lambda i, l: (l, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, npad), lambda i, l: (l, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((pt, 1), lambda i, l: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((pt, 1), lambda i, l: (i, 0),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p_pad, 1), jnp.int32),
-            jax.ShapeDtypeStruct((p_pad, 1), jnp.int32),
-        ],
+    p = dst.shape[0]
+    nl, npad = pfx_stack.shape
+    pt = min(_LPM_PT, -(-p // _LPM_SUB) * _LPM_SUB)
+    p_pad = -(-p // pt) * pt
+    width = -(-max(npad, 1) // _LPM_CHUNK) * _LPM_CHUNK
+    d = lax.bitcast_convert_type(dst.astype(jnp.uint32), jnp.int32)
+    d = jnp.pad(d, (0, p_pad - p))[:, None]
+    live = (lax.broadcasted_iota(jnp.int32, (nl, npad), 1)
+            < cnt.astype(jnp.int32)[:, None])
+    slots = jnp.where(live, slot_stack.astype(jnp.int32), _SLOT_MISS)
+    pfx = jnp.pad(pfx_stack, ((0, 0), (0, width - npad)))
+    slots = jnp.pad(slots, ((0, 0), (0, width - npad)),
+                    constant_values=_SLOT_MISS)
+    plane = pl.BlockSpec((nl, width // 128, 128), lambda i, *_: (0, 0, 0),
+                         memory_space=pltpu.VMEM)
+    plane_bytes = 2 * nl * width * 4
+    out = pl.pallas_call(
+        functools.partial(_lpm_scan_kernel, nl=nl),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(p_pad // pt,),
+            in_specs=[
+                pl.BlockSpec((pt, 1), lambda i, *_: (i, 0),
+                             memory_space=pltpu.VMEM),
+                plane,
+                plane,
+            ],
+            out_specs=pl.BlockSpec((pt, 1), lambda i, *_: (i, 0),
+                                   memory_space=pltpu.VMEM),
+        ),
+        out_shape=jax.ShapeDtypeStruct((p_pad, 1), jnp.int32),
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=2 * plane_bytes + (8 << 20)),
         cost_estimate=pl.CostEstimate(
-            flops=6 * p_pad * nl * steps,
-            bytes_accessed=(p_pad * nl * 4 + nl * (2 * npad + 1) * 4
-                            + 2 * p_pad * 4),
+            flops=3 * p_pad * nl * width,
+            bytes_accessed=plane_bytes + 2 * p_pad * 4,
             transcendentals=0,
         ),
-    )(m_cols, cnt_stack, pfx_stack, slot_stack)
-    return found[:p, 0] != 0, slot[:p, 0]
+    )(lax.bitcast_convert_type(masks.astype(jnp.uint32), jnp.int32),
+      cnt.astype(jnp.int32), d, pfx.reshape(nl, width // 128, 128),
+      slots.reshape(nl, width // 128, 128))
+    slot = out[:p, 0]
+    found = slot != _SLOT_MISS
+    return found, jnp.where(found, slot, 0)
 
 
-def _fib_lookup_lpm_pallas(tables, pkts, interpret: bool = False):
-    """``fib_lookup_lpm`` with the per-length searches running in the
-    fused kernel. The plane stacking below is TRACE-TIME bookkeeping
-    (concat of already-device-resident rows): the populated-length
-    tuple stays config-static, zero-width planes never enter the
-    stack, and the shared ``resolve_fib_slot`` tail keeps dense, LPM
-    and pallas rungs bit-exact through the same route data."""
-    from vpp_tpu.ops.fib import fib_flow_mix, resolve_fib_slot
+def lpm_fused_reference(dst, masks, cnt, pfx_stack, slot_stack):
+    """The jnp twin of ``lpm_fused_lookup`` on its own signature: a
+    left bisection per stacked length, first hit wins."""
+    found = jnp.zeros(dst.shape, bool)
+    slot = jnp.zeros(dst.shape, jnp.int32)
+    top = pfx_stack.shape[1] - 1
+    for l in range(pfx_stack.shape[0]):
+        m = _lpm_bias(dst.astype(jnp.uint32) & masks[l])
+        i = jnp.searchsorted(pfx_stack[l], m, side="left")
+        ic = jnp.clip(i, 0, top).astype(jnp.int32)
+        hit = (pfx_stack[l][ic] == m) & (i < cnt[l])
+        slot = jnp.where(hit & ~found, slot_stack[l][ic], slot)
+        found = found | hit
+    return found, slot
 
-    dst = pkts.dst_ip
+
+def _lpm_stack(tables):
+    """The populated planes stacked longest first, as the fused kernel
+    takes them: (masks [L] uint32, cnt [L] int32, pfx [L, Npad] biased
+    int32, slot [L, Npad] int32), or None when no length is populated.
+    TRACE-TIME bookkeeping over already device-resident rows: the
+    populated-length tuple stays config-static and zero-width planes
+    never enter the stack."""
     caps = tuple(getattr(tables, lpm_field(L)).shape[1]
                  for L in range(LPM_LENGTHS))
     # jax-ok: shapes — the config-static populated-length tuple
     lens = tuple(L for L in range(LPM_LENGTHS - 1, -1, -1)
                  if caps[L] > 0)
     if not lens:
-        slot = jnp.zeros(dst.shape, jnp.int32)
-        found = jnp.zeros(dst.shape, bool)
-        return resolve_fib_slot(tables, slot, found, fib_flow_mix(pkts))
+        return None
     npad = max(caps[L] for L in lens)
-    npad = ((npad + _LPM_LANES - 1) // _LPM_LANES) * _LPM_LANES
     pad_val = jnp.int32(0x7FFFFFFF)  # _lpm_bias(LPM_PAD)
     pfx_rows, slot_rows = [], []
     for L in lens:
@@ -459,15 +485,24 @@ def _fib_lookup_lpm_pallas(tables, pkts, interpret: bool = False):
         slot_rows.append(jnp.pad(plane[1].astype(jnp.int32),
                                  (0, npad - w)))
     masks = jnp.asarray([LPM_MASKS[L] for L in lens], jnp.uint32)
-    m_cols = _lpm_bias(dst[:, None] & masks[None, :])
-    found, slot = lpm_fused_lookup(
-        m_cols,
-        tables.fib_lpm_cnt[jnp.asarray(lens, jnp.int32)][:, None]
-        .astype(jnp.int32),
-        jnp.stack(pfx_rows),
-        jnp.stack(slot_rows),
-        interpret=interpret,
-    )
+    cnt = tables.fib_lpm_cnt[jnp.asarray(lens, jnp.int32)]
+    return masks, cnt.astype(jnp.int32), jnp.stack(pfx_rows), \
+        jnp.stack(slot_rows)
+
+
+def _fib_lookup_lpm_pallas(tables, pkts, interpret: bool = False):
+    """``fib_lookup_lpm`` with the per-length searches running in the
+    fused kernel; the shared ``resolve_fib_slot`` tail keeps dense,
+    LPM and pallas rungs bit-exact through the same route data."""
+    from vpp_tpu.ops.fib import fib_flow_mix, resolve_fib_slot
+
+    dst = pkts.dst_ip
+    stack = _lpm_stack(tables)
+    if stack is None:
+        slot = jnp.zeros(dst.shape, jnp.int32)
+        found = jnp.zeros(dst.shape, bool)
+    else:
+        found, slot = lpm_fused_lookup(dst, *stack, interpret=interpret)
     return resolve_fib_slot(tables, slot, found, fib_flow_mix(pkts))
 
 

@@ -47,7 +47,6 @@ from vpp_tpu.parallel.partition import (
     bv_mesh_ok,
     select_fib_impl,
     select_impl,
-    shard_map,
     table_specs,
     validate_partitioning,
 )
@@ -486,13 +485,13 @@ def make_cluster_step(mesh: Mesh, budget: int = 0, mxu: bool = False,
 
         in_specs = (t_specs, _pv_spec(), P(NODE_AXIS), P(),
                     P(NODE_AXIS))
-        return jax.jit(shard_map(
+        return jax.jit(jax.shard_map(
             body_wire, mesh=mesh, in_specs=in_specs,
             out_specs=(out_specs, P(NODE_AXIS)),
         ))
     in_specs = (t_specs, _pv_spec(), P(), P(NODE_AXIS))
     return jax.jit(
-        shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+        jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
     )
 
 

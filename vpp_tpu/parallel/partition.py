@@ -51,18 +51,6 @@ NODE_AXIS = "node"
 RULE_AXIS = "rule"
 
 
-def shard_map(*args, **kwargs):
-    """``jax.shard_map`` with a fallback to the pre-0.4.35 home
-    (``jax.experimental.shard_map``): the deployed toolchains straddle
-    the API move, and the mesh must run on both."""
-    import jax
-
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn
-    return fn(*args, **kwargs)
-
-
 class PartitionError(ValueError):
     """A DataplaneTables field resolved to no partition rule."""
 

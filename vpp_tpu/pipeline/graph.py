@@ -1024,7 +1024,30 @@ def pipeline_step_auto(
                              _tnt_pre=((tid, tnt_dropped, tbl)
                                        if tnt else None))
 
+    # Under shard_map the two tiers must agree on which mesh axes each
+    # output varies over: the fast tier folds some stats and masks to
+    # constants, which the full tier computes per node. Both branches
+    # are cast to vary over the packet batch's axes.
+    axes = frozenset().union(
+        *(jax.typeof(x).vma for x in jax.tree.leaves(orig_pkts)))
+    # jax-ok: the varying axes are part of the abstract type, static
+    # at trace time
+    if axes:
+        fast = _varying_over(fast, axes)
+        full = _varying_over(full, axes)
     return lax.cond(ok, fast, full, None)
+
+
+def _varying_over(branch, axes):
+    """``branch`` with every output leaf cast to vary over ``axes``
+    (the ``lax.cond`` branch-type rule of shard_map's manual axes)."""
+    from jax import lax
+
+    def cast(x):
+        missing = tuple(sorted(axes - jax.typeof(x).vma))
+        return lax.pcast(x, missing, to="varying") if missing else x
+
+    return lambda op: jax.tree.map(cast, branch(op))
 
 
 def _classifier_fns(impl: str):
