@@ -4286,20 +4286,6 @@ def _run():
             except Exception:  # noqa: BLE001 — already recorded above
                 pass
 
-    # per-stage `show run` snapshot (trace/cycles.py) in the official
-    # output: attributes headline movements between rounds to a stage
-    # instead of leaving regressions unexplained (VERDICT r3 Weak #2).
-    # Isolated-stage timings include one dispatch each — compare rows
-    # across ROUNDS, trust the FUSED row as the real per-frame cost.
-    stage_ns = {}
-    try:
-        from vpp_tpu.trace.cycles import profile_stages
-
-        for t in profile_stages(chain_dp.tables, cframe, iters=10):
-            stage_ns[t.node] = round(t.ns_per_packet, 1)
-    except Exception as e:  # noqa: BLE001 — diagnostics must not kill
-        stage_ns["error"] = f"{type(e).__name__}: {e}"
-
     subs = {} if args.no_subbench else sub_benches(args)
     subs.update(pri)  # priority-capture sections into the final details
     if not args.no_subbench:
@@ -4336,7 +4322,6 @@ def _run():
             # resident while_loop + io_callback refills: zero
             # per-frame dispatch (docs/LATENCY.md lever #5)
             "frame_latency_persistent_us": persistent_us,
-            "stage_ns_per_pkt": stage_ns,
             # throughput at the DEPLOYED frame size (VPP's 256-
             # packet frames), not the 65536-packet bench steps —
             # the honest companion to the batch-inflated headline

@@ -128,8 +128,12 @@ class TestSlowFetchOverlap:
         pump.start()
         try:
             # let the window fill: dispatch is far faster than the
-            # wedged fetches, so it hits the cap almost immediately
-            time.sleep(1.0)
+            # wedged fetches, so it reaches the cap quickly — poll for
+            # it (a loaded host can stretch that past any fixed sleep)
+            deadline = time.monotonic() + 20.0
+            while (pump.stats["inflight_peak"] < max_inflight
+                   and time.monotonic() < deadline):
+                time.sleep(0.02)
             # hard ceiling: the queue holds max_inflight, each fetch
             # worker can hold one dequeued item, and the writer can
             # hold one completed-but-unwritten item

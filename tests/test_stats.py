@@ -229,6 +229,9 @@ def test_pump_counters_exported_over_prometheus():
                  "chain_batches": 4, "chain_k_peak": 2,
                  "t_pack": 0.25, "t_dispatch": 1.5,
                  "t_fetch_wait": 12.75, "t_fetch": 0.5, "t_write": 2.0,
+                 "t_fetch_queue": 0.125, "t_reorder_wait": 0.375,
+                 "t_dp_upload": 0.0625, "t_dp_call": 1.25,
+                 "t_dispatch_cpu": 0.75,
                  "drops_tx_stall": 9, "drops_shutdown": 3,
                  "drops_rx_full": 0, "drops_error": 2,
                  "ring_windows": 6, "ring_frames": 11,
@@ -263,6 +266,12 @@ def test_pump_counters_exported_over_prometheus():
     assert 'vpp_tpu_pump_stage_seconds{stage="fetch_wait"} 12.75' in text
     assert 'vpp_tpu_pump_stage_seconds{stage="fetch"} 0.5' in text
     assert 'vpp_tpu_pump_stage_seconds{stage="write"} 2' in text
+    # the dispatch call split and the waits between stages
+    assert 'vpp_tpu_pump_stage_seconds{stage="fetch_queue"} 0.125' in text
+    assert 'vpp_tpu_pump_stage_seconds{stage="reorder_wait"} 0.375' in text
+    assert 'vpp_tpu_pump_stage_seconds{stage="dp_upload"} 0.0625' in text
+    assert 'vpp_tpu_pump_stage_seconds{stage="dp_call"} 1.25' in text
+    assert 'vpp_tpu_pump_stage_seconds{stage="dispatch_cpu"} 0.75' in text
     # device-ring telemetry + drop-cause attribution (ISSUE 7): the
     # io_callback-free steady state and the r5 goodput loss split are
     # exported, not inferred

@@ -1,8 +1,10 @@
-"""Packet tracer + cycle accounting tests.
+"""Packet tracer tests.
 
 Reference model: VPP `trace add` / `show trace` behavior — capture N
-packets, show per-node path including drop point — and `show run`
-per-node accounting (docs/VPP_PACKET_TRACING_K8S.md:20-50).
+packets, show per-node path including drop point
+(docs/VPP_PACKET_TRACING_K8S.md:20-50). The `show run` analog (the
+fused step's per-stage named scopes, the pump's host stage spans) is
+tested in tests/test_stage_timing.py.
 """
 
 import ipaddress
@@ -11,7 +13,7 @@ from vpp_tpu.ir import Action, ContivRule, Protocol
 from vpp_tpu.pipeline.dataplane import Dataplane
 from vpp_tpu.pipeline.tables import DataplaneConfig
 from vpp_tpu.pipeline.vector import Disposition, ip4, make_packet_vector
-from vpp_tpu.trace import PacketTracer, format_show_run, profile_stages
+from vpp_tpu.trace import PacketTracer
 
 
 def wired_dp():
@@ -118,17 +120,3 @@ def test_dataplane_auto_records_when_tracer_attached():
               rx_if=a)]
     ))
     assert len(tracer.entries()) == 1
-
-
-def test_profile_stages_show_run():
-    dp, a, b, uplink = wired_dp()
-    frame = make_packet_vector([
-        dict(src="10.1.1.2", dst="10.1.1.3", proto=6, sport=1, dport=80,
-             rx_if=a)
-    ])
-    timings = profile_stages(dp.tables, frame, iters=2)
-    names = {t.node for t in timings}
-    assert "ip4-input" in names and "FUSED pipeline-step" in names
-    assert all(t.seconds_per_call >= 0 for t in timings)
-    table = format_show_run(timings)
-    assert "ns/packet" in table and "acl-classify-local" in table

@@ -37,8 +37,7 @@ JIT_SITES = {
         "diagnostic classify probe; per-impl cache on the instance, "
         "bench/operator path — never hot",
     ("vpp_tpu/pipeline/graph.py", "<module>"):
-        "pipeline_step_jit: the module-level reference step (tests, "
-        "trace/cycles)",
+        "pipeline_step_jit: the module-level reference step (tests)",
     ("vpp_tpu/pipeline/tables.py", "_glb_update_fn"):
         "incremental glb-blob upload kernel; memoized per (w_r, w_c, "
         "planes) block geometry",

@@ -95,6 +95,7 @@ from vpp_tpu.pipeline.dataplane import (
 )
 from vpp_tpu.pipeline.vector import Disposition, PacketVector
 from vpp_tpu.testing import faults
+from vpp_tpu.trace.timed import Timed
 
 log = logging.getLogger("pump")
 
@@ -290,6 +291,22 @@ class DataplanePump:
             # the other in-flight batches, not a serial path cost.
             "t_pack": 0.0, "t_dispatch": 0.0, "t_fetch": 0.0,
             "t_fetch_wait": 0.0, "t_write": 0.0,
+            # inside t_dispatch: the dataplane's upload of the packed
+            # batch and scalars, and its jitted step call (folded from
+            # dp.host_timers after each call); t_dispatch_cpu is the
+            # dispatch thread's CPU time over the t_dispatch intervals
+            # (wall minus CPU = time it did not run: GIL, locks, a
+            # blocking transfer)
+            "t_dp_upload": 0.0, "t_dp_call": 0.0, "t_dispatch_cpu": 0.0,
+            # waits between stages, per batch: hand-off put to a fetch
+            # worker's take (t_fetch_queue), fetch worker's post to the
+            # tx writer's pop (t_reorder_wait); t_resident is
+            # frame-weighted, frames x (tx commit - take from the rx
+            # ring) per written group — divide by frames
+            "t_fetch_queue": 0.0, "t_reorder_wait": 0.0, "t_resident": 0.0,
+            # frames pending in the rx ring, not yet taken, just before
+            # each dispatching take — divide by batches
+            "rx_backlog_sum": 0,
             # overlap occupancy: batches dispatched but not yet written
             # (the ladder's live depth) + high-water mark, and how often
             # the adaptive chainer folded backlog into one K-stack
@@ -935,10 +952,19 @@ class DataplanePump:
         with self._lat_lock:
             self.stats[drop_key] += sum(f.n for g in groups for f in g)
         self._inflight_inc()
+        now = time.perf_counter()
         with self._done_cv:
-            self._done[self._seq] = (None, groups, None,
-                                     time.perf_counter(), False, False)
+            self._done[self._seq] = (None, groups, None, now, False, False,
+                                     now, now)
             self._seq += 1
+            self._done_cv.notify_all()
+
+    def _post_done(self, seq: int, item: tuple) -> None:
+        """Hand a fetched batch to the tx writer: ``item`` is (batch,
+        groups, non_ip, t0, fast, pri, t_take); the post time goes on
+        the end, for the writer's t_reorder_wait."""
+        with self._done_cv:
+            self._done[seq] = item + (time.perf_counter(),)
             self._done_cv.notify_all()
 
     def _shed_group(self, groups: list) -> None:
@@ -1085,14 +1111,16 @@ class DataplanePump:
                     if self.stats["inflight"] >= g_infl:
                         time.sleep(self.poll_s)
                         continue
-                taken = self._take_tenant_group(rx, max_pkts)
+                with Timed("pump.take"):
+                    taken = self._take_tenant_group(rx, max_pkts)
                 if taken is None:
                     time.sleep(self.poll_s)
                     continue
                 self._dispatch_or_fail(taken[1], slow)
                 continue
-            groups = self._take_groups(rx, hold_cap, chain_cap,
-                                       max_pkts)
+            with Timed("pump.take"):
+                groups = self._take_groups(rx, hold_cap, chain_cap,
+                                           max_pkts)
             if not groups:
                 time.sleep(self.poll_s)
                 continue
@@ -1147,61 +1175,74 @@ class DataplanePump:
     def _dispatch(self, groups: list, slow: bool = False,
                   pri: bool = False) -> None:
         K = len(groups)
-        tp0 = time.perf_counter()
-        # rx-enqueue stamp for the device wire-latency histogram
-        # (ISSUE 11): pack start ≈ the frames' peek time in dispatch
-        # mode, so the histogram covers pack + the dispatch queue
-        stamp_us = 0
-        if getattr(self.dp, "_tel_mode", "off") != "off":
-            from vpp_tpu.ops.telemetry import tel_clock_us
+        # frames pending and untaken just before this take: those
+        # still untaken now plus the ones the take just took
+        backlog = self._backlog() + sum(len(g) for g in groups)
+        # the take's time for the residence counter: pack start, right
+        # after the take
+        with Timed("pump.pack", self.stats, "t_pack") as pack:
+            # rx-enqueue stamp for the device wire-latency histogram
+            # (ops/telemetry.py): pack start ≈ the frames' peek time in
+            # dispatch mode, so the histogram covers pack + the
+            # dispatch queue
+            stamp_us = 0
+            if getattr(self.dp, "_tel_mode", "off") != "off":
+                from vpp_tpu.ops.telemetry import tel_clock_us
 
-            stamp_us = tel_clock_us()
-        if K == 1:
-            total = sum(f.n for f in groups[0])
-            # pad to the smallest ladder bucket that fits (a compile
-            # costs 20-40 s on TPU, so the ladder is geometric, not
-            # per-size): a single frame dispatches at VEC for latency;
-            # larger backlogs climb the rungs
-            bucket = next(b for b in self.buckets if b >= total)
-            flat = np.zeros((PACKED_IN_ROWS, bucket), np.int32)
-            non_ip = np.zeros(bucket, np.uint8)
-            self._pack_group(groups[0], flat, non_ip)
-        else:
-            # chain fold: K stacked max_batch buckets, ONE device
-            # program. K is a power of two from the precompiled rung
-            # ladder (``_take_groups`` trimmed to it), so the jit
-            # cache stays at log2(chain_k) chain shapes.
-            flat = np.zeros((K, PACKED_IN_ROWS,
-                             self.max_batch), np.int32)
-            non_ip = np.zeros((K, self.max_batch), np.uint8)
-            for k, g in enumerate(groups):
-                self._pack_group(g, flat[k], non_ip[k])
-        non_ip = non_ip.view(bool)
-        self.stats["t_pack"] += time.perf_counter() - tp0
-        t0 = time.perf_counter()
-        if slow:
-            # tracing: run the unpacked step so the tracer captures a
-            # full StepResult (multi-transfer — fine while debugging)
-            payload = self.dp.process(
-                PacketVector(**unpack_packet_input(flat))
-            )
-        elif K == 1:
-            # async dispatch; (out, aux) with the fast-path summary
-            # riding the same program (measured on both tiers)
-            payload = self.dp.process_packed(flat, with_aux=True,
-                                             stamp_us=stamp_us)
-        else:
-            # async, ([K,5,B], [K,PACKED_AUX_ROWS])
-            payload = self.dp.process_packed_chain(
-                flat, with_aux=True,
-                stamps_us=np.full(K, stamp_us, np.int32))
-            self.stats["chain_batches"] += 1
-            self.stats["chain_k_peak"] = max(self.stats["chain_k_peak"],
-                                             K)
-        self.stats["t_dispatch"] += time.perf_counter() - t0
+                stamp_us = tel_clock_us()
+            if K == 1:
+                total = sum(f.n for f in groups[0])
+                # pad to the smallest ladder bucket that fits (a
+                # compile costs 20-40 s on TPU, so the ladder is
+                # geometric, not per-size): a single frame dispatches
+                # at VEC for latency; larger backlogs climb the rungs
+                bucket = next(b for b in self.buckets if b >= total)
+                flat = np.zeros((PACKED_IN_ROWS, bucket), np.int32)
+                non_ip = np.zeros(bucket, np.uint8)
+                self._pack_group(groups[0], flat, non_ip)
+            else:
+                # chain fold: K stacked max_batch buckets, ONE device
+                # program. K is a power of two from the precompiled
+                # rung ladder (``_take_groups`` trimmed to it), so the
+                # jit cache stays at log2(chain_k) chain shapes.
+                flat = np.zeros((K, PACKED_IN_ROWS,
+                                 self.max_batch), np.int32)
+                non_ip = np.zeros((K, self.max_batch), np.uint8)
+                for k, g in enumerate(groups):
+                    self._pack_group(g, flat[k], non_ip[k])
+            non_ip = non_ip.view(bool)
+        timers = getattr(self.dp, "host_timers", None)
+        timers0 = dict(timers) if timers is not None else {}
+        with Timed("pump.dispatch", self.stats, "t_dispatch",
+                   cpu_key="t_dispatch_cpu") as disp:
+            if slow:
+                # tracing: run the unpacked step so the tracer captures
+                # a full StepResult (multi-transfer — fine while
+                # debugging)
+                payload = self.dp.process(
+                    PacketVector(**unpack_packet_input(flat))
+                )
+            elif K == 1:
+                # async dispatch; (out, aux) with the fast-path summary
+                # riding the same program (measured on both tiers)
+                payload = self.dp.process_packed(flat, with_aux=True,
+                                                 stamp_us=stamp_us)
+            else:
+                # async, ([K,5,B], [K,PACKED_AUX_ROWS])
+                payload = self.dp.process_packed_chain(
+                    flat, with_aux=True,
+                    stamps_us=np.full(K, stamp_us, np.int32))
+                self.stats["chain_batches"] += 1
+                self.stats["chain_k_peak"] = max(
+                    self.stats["chain_k_peak"], K)
+        for k, v in timers0.items():
+            self.stats[k] += timers[k] - v
+        t0 = disp.t0
         # unlocked: the dispatch thread is _seq's only writer, so its
-        # own read needs no lock; increments publish under _done_cv
-        item = (self._seq, payload, groups, non_ip, t0, slow, pri)
+        # own read needs no lock; increments publish under _done_cv.
+        # The last field is the hand-off time (t_fetch_queue).
+        item = (self._seq, payload, groups, non_ip, t0, slow, pri,
+                pack.t0, time.perf_counter())
         # count the batch in flight BEFORE the hand-off: a fetch worker
         # can complete it (and the writer decrement it) the instant the
         # put lands, so inc-after-put would transiently read -1
@@ -1227,6 +1268,7 @@ class DataplanePump:
         with self._done_cv:
             self._seq += 1
         self.stats["batches"] += 1
+        self.stats["rx_backlog_sum"] += backlog
         self.stats["max_coalesce"] = max(self.stats["max_coalesce"],
                                          sum(len(g) for g in groups))
 
@@ -1334,60 +1376,36 @@ class DataplanePump:
         ticketed, so the dispatch-mode loop that takes over re-peeks
         and serves them; nothing is dropped by the mode switch
         itself)."""
-        tp0 = time.perf_counter()
-        # rx-enqueue stamp (ISSUE 11): taken at pack start so the
-        # device-side wire-latency histogram covers pack + submit
-        # queueing + window fill + ring backpressure — the whole host
-        # leg up to the dispatch the governor (ROADMAP item 3) can
-        # actually influence. 0 (unstamped) with telemetry off.
-        stamp_us = 0
-        if getattr(self.dp, "_tel_mode", "off") != "off":
-            from vpp_tpu.ops.telemetry import tel_clock_us
+        # as in _dispatch: the backlog before the take, the take's time
+        backlog = self._backlog() + len(frames)
+        with Timed("pump.pack", self.stats, "t_pack") as pack:
+            # rx-enqueue stamp (ops/telemetry.py): taken at pack start
+            # so the device-side wire-latency histogram covers pack +
+            # submit queueing + window fill + ring backpressure — the
+            # whole host leg up to the dispatch the governor can
+            # actually influence. 0 (unstamped) with telemetry off.
+            stamp_us = 0
+            if getattr(self.dp, "_tel_mode", "off") != "off":
+                from vpp_tpu.ops.telemetry import tel_clock_us
 
-            stamp_us = tel_clock_us()
-        flat = np.zeros((PACKED_IN_ROWS, VEC), np.int32)
-        non_ip = np.zeros(VEC, np.uint8)
-        self._pack_group(frames, flat, non_ip)
-        self.stats["t_pack"] += time.perf_counter() - tp0
-        t0 = time.perf_counter()
-        while True:
-            try:
-                self._ppump.submit(flat, now=self.dp.clock_ticks(),
-                                   stamp_us=stamp_us,
-                                   priority=priority)
-                if self._ring_backoff.attempt:
-                    self._ring_backoff.reset()
-                break
-            except RuntimeError:
-                self._ring_faults += 1
-                log.exception("resident loop died (ring fault %d%s)",
-                              self._ring_faults,
-                              f"/{self.ring_fault_limit}"
-                              if self.ring_fault_limit else "")
-                self.stats["batch_errors"] += 1
-                # fold the dead ring's counters before replacing it, or
-                # the exported ring_windows/ring_frames totals would
-                # jump backwards (a spurious counter reset for scrapers)
-                self._ring_fold(self._ppump)
-                self._ppump = None
-                if self.ring_fault_limit and \
-                        self._ring_faults >= self.ring_fault_limit:
-                    self._untake_any(frames, priority, tenant)
-                    return "fallback"
-                time.sleep(self._ring_backoff.next())
-                try:
-                    self._persist_start()
-                except Exception:  # noqa: BLE001 — a relaunch that
-                    # cannot even start IS the wedged-ring case the
-                    # fallback exists for, whatever the limit says
-                    log.exception("resident loop relaunch failed")
-                    self._untake_any(frames, priority, tenant)
-                    return "fallback"
-        self.stats["t_dispatch"] += time.perf_counter() - t0
+                stamp_us = tel_clock_us()
+            flat = np.zeros((PACKED_IN_ROWS, VEC), np.int32)
+            non_ip = np.zeros(VEC, np.uint8)
+            self._pack_group(frames, flat, non_ip)
+        with Timed("pump.dispatch", self.stats, "t_dispatch",
+                   cpu_key="t_dispatch_cpu") as disp:
+            if self._persist_submit(flat, stamp_us, priority):
+                # a group handed back to the dispatch ladder was never
+                # dispatched: its time stays out of the counters
+                disp.cancel()
+                self._untake_any(frames, priority, tenant)
+                return "fallback"
+        t0 = disp.t0
         # unlocked: the dispatch thread is _seq's only writer, so its
-        # own read needs no lock; increments publish under _done_cv
+        # own read needs no lock; increments publish under _done_cv.
+        # The last field is the hand-off time (t_fetch_queue).
         item = (self._seq, self._ppump, [frames], non_ip.view(bool), t0,
-                priority)
+                priority, pack.t0, time.perf_counter())
         self._inflight_inc()
         while True:
             try:
@@ -1405,9 +1423,48 @@ class DataplanePump:
         with self._done_cv:
             self._seq += 1
         self.stats["batches"] += 1
+        self.stats["rx_backlog_sum"] += backlog
         self.stats["max_coalesce"] = max(self.stats["max_coalesce"],
                                          len(frames))
         return "ok"
+
+    def _persist_submit(self, flat: np.ndarray, stamp_us: int,
+                        priority: bool) -> bool:
+        """Submit one packed slot to the ring pump, relaunching a dead
+        ring. Returns True when repeated ring deaths hit
+        ``ring_fault_limit`` (or a relaunch cannot start): the caller
+        falls back to the dispatch ladder."""
+        while True:
+            try:
+                self._ppump.submit(flat, now=self.dp.clock_ticks(),
+                                   stamp_us=stamp_us,
+                                   priority=priority)
+                if self._ring_backoff.attempt:
+                    self._ring_backoff.reset()
+                return False
+            except RuntimeError:
+                self._ring_faults += 1
+                log.exception("resident loop died (ring fault %d%s)",
+                              self._ring_faults,
+                              f"/{self.ring_fault_limit}"
+                              if self.ring_fault_limit else "")
+                self.stats["batch_errors"] += 1
+                # fold the dead ring's counters before replacing it, or
+                # the exported ring_windows/ring_frames totals would
+                # jump backwards (a spurious counter reset for scrapers)
+                self._ring_fold(self._ppump)
+                self._ppump = None
+                if self.ring_fault_limit and \
+                        self._ring_faults >= self.ring_fault_limit:
+                    return True
+                time.sleep(self._ring_backoff.next())
+                try:
+                    self._persist_start()
+                except Exception:  # noqa: BLE001 — a relaunch that
+                    # cannot even start IS the wedged-ring case the
+                    # fallback exists for, whatever the limit says
+                    log.exception("resident loop relaunch failed")
+                    return True
 
     def _persist_dispatch_loop(self) -> None:
         rx = self.rings.rx
@@ -1676,32 +1733,33 @@ class DataplanePump:
             self.stats["ring_lag"] = int(live.get("ring_lag", 0))
 
     def _persist_collect_one(self, item) -> None:
-        seq, ppump, groups, non_ip, t0, pri = item
-        tf0 = time.perf_counter()
+        seq, ppump, groups, non_ip, t0, pri, t_take, t_put = item
         batch = None
         fast = False
         deadline = time.monotonic() + 300.0
-        # NOT gated on _stop: an already-submitted frame's result
-        # is coming (PersistentPump.stop drains every queued frame
-        # before the loop exits) — discarding it at pump shutdown
-        # would silently drop live traffic the dispatch mode
-        # delivers. Loop-death/timeout still bounds the wait.
-        while True:
-            try:
-                batch, aux = ppump.result_ex(timeout=0.2)
-                fast = self._account_fastpath(aux)
-                break
-            except queue.Empty:
-                if time.monotonic() > deadline:
-                    log.error("resident loop result timed out")
+        with Timed("pump.fetch", self.stats, "t_fetch",
+                   lock=self._lat_lock) as fetch:
+            # NOT gated on _stop: an already-submitted frame's result
+            # is coming (PersistentPump.stop drains every queued frame
+            # before the loop exits) — discarding it at pump shutdown
+            # would silently drop live traffic the dispatch mode
+            # delivers. Loop-death/timeout still bounds the wait.
+            while True:
+                try:
+                    batch, aux = ppump.result_ex(timeout=0.2)
+                    fast = self._account_fastpath(aux)
+                    break
+                except queue.Empty:
+                    if time.monotonic() > deadline:
+                        log.error("resident loop result timed out")
+                        self.stats["batch_errors"] += 1
+                        break
+                except RuntimeError:
+                    log.exception("resident loop result failed")
                     self.stats["batch_errors"] += 1
                     break
-            except RuntimeError:
-                log.exception("resident loop result failed")
-                self.stats["batch_errors"] += 1
-                break
         with self._lat_lock:
-            self.stats["t_fetch"] += time.perf_counter() - tf0
+            self.stats["t_fetch_queue"] += fetch.t0 - t_put
             if batch is None:
                 # the frames will be released unwritten by the writer:
                 # attribute the loss. The ring drains every queued
@@ -1711,9 +1769,7 @@ class DataplanePump:
                 self.stats["drops_error"] += sum(
                     f.n for g in groups for f in g)
         self._ring_stats_sync()
-        with self._done_cv:
-            self._done[seq] = (batch, groups, non_ip, t0, fast, pri)
-            self._done_cv.notify_all()
+        self._post_done(seq, (batch, groups, non_ip, t0, fast, pri, t_take))
 
     def _persist_collect_loop(self) -> None:
         """Pull ordered results off the resident loop and hand them to
@@ -1778,7 +1834,10 @@ class DataplanePump:
         stop sentinel)."""
         import jax
 
-        seq, payload, groups, non_ip, t0, slow, pri = item
+        seq, payload, groups, non_ip, t0, slow, pri, t_take, t_put = item
+        queued = time.perf_counter() - t_put
+        with self._lat_lock:
+            self.stats["t_fetch_queue"] += queued
         delay = self._fetch_delay
         if delay is not None:
             time.sleep(delay(seq) if callable(delay) else delay)
@@ -1822,20 +1881,19 @@ class DataplanePump:
                 # view of a device buffer whose lifetime ends with
                 # `payload` — the copy (20 B/packet) outlives it
                 out, aux = payload  # aux: [3] (or [K,3]) tier summary
-                tw0 = time.perf_counter()
-                jax.block_until_ready(payload)
-                tf0 = time.perf_counter()
-                # one fetch for both: the aux summary (12 B) must not
-                # cost a second round trip on a remote transport
-                out_h, aux_h = jax.device_get((out, aux))
-                count_device_transfer("pump.fetch.packed", (out_h, aux_h))
-                batch = np.array(out_h)
-                tf1 = time.perf_counter()
                 # concurrent fetchers: accumulate under a lock or
                 # the += load/add/store interleaves and undercounts
-                with self._lat_lock:
-                    self.stats["t_fetch_wait"] += tf0 - tw0
-                    self.stats["t_fetch"] += tf1 - tf0
+                with Timed("pump.fetch_wait", self.stats, "t_fetch_wait",
+                           lock=self._lat_lock):
+                    jax.block_until_ready(payload)
+                with Timed("pump.fetch", self.stats, "t_fetch",
+                           lock=self._lat_lock):
+                    # one fetch for both: the aux summary (12 B) must
+                    # not cost a second round trip on a remote transport
+                    out_h, aux_h = jax.device_get((out, aux))
+                    count_device_transfer("pump.fetch.packed",
+                                          (out_h, aux_h))
+                    batch = np.array(out_h)
                 fast = self._account_fastpath(aux_h)
         except Exception:
             log.exception("pump fetch failed (batch %d)", seq)
@@ -1846,9 +1904,7 @@ class DataplanePump:
                 # attribute the loss, don't just count a batch error
                 self.stats["drops_error"] += sum(
                     f.n for g in groups for f in g)
-        with self._done_cv:
-            self._done[seq] = (batch, groups, non_ip, t0, fast, pri)
-            self._done_cv.notify_all()
+        self._post_done(seq, (batch, groups, non_ip, t0, fast, pri, t_take))
 
     def _account_fastpath(self, aux) -> bool:
         """Fold one dispatch's ``[PACKED_AUX_ROWS]`` (or chain-fold
@@ -1963,8 +2019,12 @@ class DataplanePump:
                         if stranded is not _SENTINEL:
                             self._complete_item(stranded)
                 continue
+            if item[0] is not None:
+                # the fetch worker's post to this pop (item[7])
+                self.stats["t_reorder_wait"] += (time.perf_counter()
+                                                 - item[7])
             try:
-                self._write(*item)
+                self._write(*item[:7])
             except Exception:
                 log.exception("pump tx write failed")
                 self._release_done(item[1])
@@ -1972,12 +2032,14 @@ class DataplanePump:
 
     def _write_packed_group(self, batch: np.ndarray, frames: list,
                             host_if: int, epoch: int,
-                            icmp_on: bool) -> None:
+                            icmp_on: bool, t_take: float) -> None:
         """Fast path for one coalesce group: ONE native call per frame
         decodes the packed [5, B] result straight into a reserved tx
         slot (pass-through columns from the rx slot, non-IP punt
-        applied in C)."""
+        applied in C). The frames written add their residence since
+        the take (``t_take``) to t_resident."""
         off = 0
+        written = 0
         for f in frames:
             n = f.n
             with self._tx_lock:
@@ -1994,31 +2056,35 @@ class DataplanePump:
             if ok:
                 self.stats["frames"] += 1
                 self.stats["pkts"] += n
+                written += 1
                 if icmp_on and n and self._cause[:n].any():
                     self._emit_icmp_frame(f, self._cause)
             else:
                 self.stats["tx_ring_full"] += 1
                 self.stats["drops_tx_stall"] += n
             off += n
+        self.stats["t_resident"] += written * (time.perf_counter()
+                                               - t_take)
 
     def _write(self, batch, groups: list, non_ip, t0: float,
-               fast: bool = False, pri: bool = False) -> None:
+               fast: bool, pri: bool, t_take: float) -> None:
         if isinstance(batch, np.ndarray):
-            tw0 = time.perf_counter()
-            host_if = (self.dp.host_if
-                       if self.dp.host_if is not None else -1)
-            epoch = self.dp.epoch
-            icmp_on = self.icmp is not None
-            if batch.ndim == 3:
-                # chain fold: sub-batch k carries group k's packets
-                # (padded stack rows past len(groups) hold no frames)
-                for k, frames in enumerate(groups):
-                    self._write_packed_group(batch[k], frames, host_if,
-                                             epoch, icmp_on)
-            else:
-                self._write_packed_group(batch, groups[0], host_if,
-                                         epoch, icmp_on)
-            self.stats["t_write"] += time.perf_counter() - tw0
+            with Timed("pump.write", self.stats, "t_write"):
+                host_if = (self.dp.host_if
+                           if self.dp.host_if is not None else -1)
+                epoch = self.dp.epoch
+                icmp_on = self.icmp is not None
+                if batch.ndim == 3:
+                    # chain fold: sub-batch k carries group k's packets
+                    # (padded stack rows past len(groups) hold no
+                    # frames)
+                    for k, frames in enumerate(groups):
+                        self._write_packed_group(batch[k], frames,
+                                                 host_if, epoch, icmp_on,
+                                                 t_take)
+                else:
+                    self._write_packed_group(batch, groups[0], host_if,
+                                             epoch, icmp_on, t_take)
             lat = time.perf_counter() - t0
             with self._lat_lock:
                 self.batch_lat.append(lat)
@@ -2044,6 +2110,7 @@ class DataplanePump:
             batch["rx_if"] = batch.pop("tx_if")  # tx direction: egress if
             epoch = self.dp.epoch
             off = 0
+            written = 0
             for f in frames:
                 n = f.n
                 out_cols = {}
@@ -2066,6 +2133,7 @@ class DataplanePump:
                 if ok:
                     self.stats["frames"] += 1
                     self.stats["pkts"] += n
+                    written += 1
                     # ICMP only for frames that made it out: under tx
                     # backpressure the error frames drop with the
                     # traffic (same policy as the fast path)
@@ -2078,6 +2146,8 @@ class DataplanePump:
                     self.stats["tx_ring_full"] += 1
                     self.stats["drops_tx_stall"] += n
                 off += n
+            self.stats["t_resident"] += written * (time.perf_counter()
+                                                   - t_take)
             lat = time.perf_counter() - t0
             with self._lat_lock:
                 self.batch_lat.append(lat)

@@ -134,7 +134,7 @@ def fib_lookup_dense(tables: DataplaneTables,
 
 
 def ip4_lookup(tables: DataplaneTables, dst_ip: jnp.ndarray) -> FibResult:
-    """Header-only legacy entry (trace/cycles.py, direct tests): LPM
+    """Header-only legacy entry (direct tests): LPM
     lookup of ``dst_ip`` [P] against the FIB slots, dense form. With
     no 5-tuple available the ECMP member pick degrades to a zero flow
     mix (member way 0) — unicast routes are unaffected; callers on the

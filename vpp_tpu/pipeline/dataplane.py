@@ -35,6 +35,7 @@ from vpp_tpu.pipeline.tables import (
 from vpp_tpu.ops.vxlan import vxlan_encap
 from vpp_tpu.pipeline.vector import Disposition, PacketVector
 from vpp_tpu.trace import spans
+from vpp_tpu.trace.timed import Timed
 
 
 def _packed_call(step, with_aux: bool = False, tel: str = "off"):
@@ -809,6 +810,13 @@ class Dataplane:
         self.classify_seconds = 0.0
         self.classify_ns_pkt: Optional[float] = None
         self._classify_probe_cache: Dict[str, object] = {}
+        # cumulative host seconds inside process_packed[_chain]: turning
+        # the host arguments into device arrays (t_dp_upload) and the
+        # jitted step call until it returns (t_dp_call; dispatch is
+        # asynchronous, so this is the host's side of the call, not the
+        # device step). The pump folds the deltas of each dispatch into
+        # its own stats (spans dp.upload / dp.step_call).
+        self.host_timers = {"t_dp_upload": 0.0, "t_dp_call": 0.0}
         # Session time base: wall-clock ticks (TICKS_PER_SEC), not frame
         # counts — aging semantics must not depend on offered load
         # (VERDICT r1 Weak #5; the reference ages on timers).
@@ -1487,17 +1495,18 @@ class Dataplane:
             if now is None:
                 self._now = max(self._now, self.clock_ticks())
                 now = self._now
-        if self._tel_mode != "off":
+        tel = self._tel_mode != "off"
+        if tel and now_us is None:
             from vpp_tpu.ops.telemetry import tel_clock_us
 
-            if now_us is None:
-                now_us = tel_clock_us()
-            new_tables, out, aux = step(
-                tables, jnp.asarray(flat), jnp.int32(now),
-                jnp.int32(stamp_us), jnp.int32(now_us))
-        else:
-            new_tables, out, aux = step(tables, jnp.asarray(flat),
-                                        jnp.int32(now))
+            now_us = tel_clock_us()
+        timers = self.host_timers
+        with Timed("dp.upload", timers, "t_dp_upload"):
+            args = (jnp.asarray(flat), jnp.int32(now))
+            if tel:
+                args += (jnp.int32(stamp_us), jnp.int32(now_us))
+        with Timed("dp.step_call", timers, "t_dp_call"):
+            new_tables, out, aux = step(tables, *args)
         if commit:
             with self._lock:
                 if tables is self.tables:
@@ -1531,20 +1540,22 @@ class Dataplane:
             if now is None:
                 self._now = max(self._now, self.clock_ticks())
                 now = self._now
-        if self._tel_mode != "off":
+        tel = self._tel_mode != "off"
+        if tel:
             from vpp_tpu.ops.telemetry import tel_clock_us
 
             if now_us is None:
                 now_us = tel_clock_us()
             if stamps_us is None:
                 stamps_us = np.zeros(len(flats), np.int32)
-            new_tables, (outs, auxs) = step(
-                tables, jnp.asarray(flats), jnp.int32(now),
-                jnp.asarray(stamps_us, jnp.int32), jnp.int32(now_us))
-        else:
-            new_tables, (outs, auxs) = step(
-                tables, jnp.asarray(flats), jnp.int32(now)
-            )
+        timers = self.host_timers
+        with Timed("dp.upload", timers, "t_dp_upload"):
+            args = (jnp.asarray(flats), jnp.int32(now))
+            if tel:
+                args += (jnp.asarray(stamps_us, jnp.int32),
+                         jnp.int32(now_us))
+        with Timed("dp.step_call", timers, "t_dp_call"):
+            new_tables, (outs, auxs) = step(tables, *args)
         with self._lock:
             if tables is self.tables:
                 self.tables = new_tables

@@ -173,6 +173,29 @@ class StepResult(NamedTuple):
                                               # (-1 where not encapped)
 
 
+# Every op of the fused step is traced under one of these
+# ``jax.named_scope`` names (the shared tail's encap nests as
+# ``tail/overlay``), so a profiler trace or the compiled HLO's
+# ``op_name`` metadata splits the one fused program by graph stage:
+# the `show run` per-node accounting of the reference, under XLA.
+STAGE_SCOPES = ("overlay", "ip4-input", "tenant", "session", "nat", "ml",
+                "classify", "fib", "tail")
+
+
+def _stage(name: str):
+    """Decorator: trace the function under ``jax.named_scope(name)``,
+    a fresh scope per call (``named_scope``'s own decorator form keeps
+    one instance, which two threads tracing at once would share)."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped
+    return wrap
+
+
+@_stage("ip4-input")
 def _ingress(tables: DataplaneTables, pkts: PacketVector):
     """Shared ingress prologue of every pipeline tier: ip4-input plus
     the unconfigured-interface drop (VPP analog: unknown sw_if_index →
@@ -185,6 +208,7 @@ def _ingress(tables: DataplaneTables, pkts: PacketVector):
     return pkts, drop_ip4, pkts.valid & ~drop_ip4
 
 
+@_stage("tenant")
 def _tenant_eval(tables: DataplaneTables, pkts: PacketVector,
                  alive: jnp.ndarray, now, tnt_mode: str,
                  ovl_tid=None, ovl_decapped=None):
@@ -220,6 +244,7 @@ def _tenant_eval(tables: DataplaneTables, pkts: PacketVector,
     return tid, dropped, tables
 
 
+@_stage("ml")
 def _ml_eval(tables: DataplaneTables, pkts: PacketVector,
              alive: jnp.ndarray, established: jnp.ndarray,
              sess_age: jnp.ndarray, ml_mode: str, ml_kind: str,
@@ -256,6 +281,7 @@ def _ml_eval(tables: DataplaneTables, pkts: PacketVector,
     return alive, flagged, drop_wanted, scores
 
 
+@_stage("tail")
 def _finish_step(
     tables: DataplaneTables,
     pkts: PacketVector,
@@ -330,31 +356,33 @@ def _finish_step(
     if overlay != "off":
         from vpp_tpu.ops.vxlan import DEFAULT_VNI, vxlan_encap
 
-        ovl_need = (forwarded & (disp == int(Disposition.REMOTE))
-                    & (fib.next_hop != 0))
-        ovl_outer = vxlan_encap(pkts, ovl_need, tables.ovl_vtep_ip,
-                                fib.next_hop)
-        ofib = fib_fn(tables, ovl_outer)
-        ofib_ok = ofib.matched & (ofib.disp != int(Disposition.DROP))
-        ovl_miss = ovl_need & ~ofib_ok
-        forwarded = forwarded & ~ovl_miss
-        disp = jnp.where(ovl_miss, int(Disposition.DROP),
-                         disp).astype(jnp.int32)
-        ovl_encap = ovl_need & ofib_ok
-        tx_if = jnp.where(ovl_encap, ofib.tx_if,
-                          jnp.where(ovl_miss, -1, tx_if))
-        ovl_outer = ovl_outer._replace(
-            flags=jnp.where(ovl_encap, ovl_outer.flags, 0))
-        # per-tenant VNI on the wire: the tenant's configured VNI
-        # (tnt_vni — tenancy off keeps slot 0 at DEFAULT_VNI), with
-        # DEFAULT_VNI covering tenants that configured none
-        # jax-ok: tid None-ness is the trace-time-static tnt gate
-        if tid is not None:
-            vni_raw = tables.tnt_vni[tid]
-        else:
-            vni_raw = jnp.broadcast_to(tables.tnt_vni[0], alive.shape)
-        vni = jnp.where(vni_raw >= 0, vni_raw, DEFAULT_VNI)
-        ovl_vni_out = jnp.where(ovl_encap, vni, -1).astype(jnp.int32)
+        with jax.named_scope("overlay"):
+            ovl_need = (forwarded & (disp == int(Disposition.REMOTE))
+                        & (fib.next_hop != 0))
+            ovl_outer = vxlan_encap(pkts, ovl_need, tables.ovl_vtep_ip,
+                                    fib.next_hop)
+            ofib = fib_fn(tables, ovl_outer)
+            ofib_ok = ofib.matched & (ofib.disp != int(Disposition.DROP))
+            ovl_miss = ovl_need & ~ofib_ok
+            forwarded = forwarded & ~ovl_miss
+            disp = jnp.where(ovl_miss, int(Disposition.DROP),
+                             disp).astype(jnp.int32)
+            ovl_encap = ovl_need & ofib_ok
+            tx_if = jnp.where(ovl_encap, ofib.tx_if,
+                              jnp.where(ovl_miss, -1, tx_if))
+            ovl_outer = ovl_outer._replace(
+                flags=jnp.where(ovl_encap, ovl_outer.flags, 0))
+            # per-tenant VNI on the wire: the tenant's configured VNI
+            # (tnt_vni — tenancy off keeps slot 0 at DEFAULT_VNI), with
+            # DEFAULT_VNI covering tenants that configured none
+            # jax-ok: tid None-ness is the trace-time-static tnt gate
+            if tid is not None:
+                vni_raw = tables.tnt_vni[tid]
+            else:
+                vni_raw = jnp.broadcast_to(tables.tnt_vni[0],
+                                           alive.shape)
+            vni = jnp.where(vni_raw >= 0, vni_raw, DEFAULT_VNI)
+            ovl_vni_out = jnp.where(ovl_encap, vni, -1).astype(jnp.int32)
     else:
         ovl_miss = jnp.zeros(alive.shape, bool)
         ovl_encap = jnp.zeros(alive.shape, bool)
@@ -574,8 +602,9 @@ def pipeline_step(
     if overlay != "off":
         from vpp_tpu.ops.vxlan import vxlan_decap_step
 
-        pkts, ovl_bad, ovl_decapped, ovl_tid = vxlan_decap_step(
-            tables, pkts, ovl_inner, ovl_vni)
+        with jax.named_scope("overlay"):
+            pkts, ovl_bad, ovl_decapped, ovl_tid = vxlan_decap_step(
+                tables, pkts, ovl_inner, ovl_vni)
     else:
         ovl_bad = ovl_decapped = ovl_tid = None
 
@@ -614,24 +643,26 @@ def pipeline_step(
     # post-DNAT, so a backend's reply B→C reverses to the stored C→B key.
     # Expired entries (idle > sess_max_age ticks) don't match, and hits
     # refresh the timestamp — active flows never expire mid-flow.
-    established, sess_hit_idx = session_lookup_reverse_idx(
-        tables, pkts, now, shard=shard, tnt=tnt, impl=sess_impl,
-        sym=sess_hash == "sym")
-    established = established & alive
-    # pre-touch session age: an ML feature (the touch below refreshes
-    # the timestamp, so the age must be captured first — the fast tier
-    # captures it at the same pre-touch point, docs/ML_STAGE.md)
-    sess_age = session_hit_age(tables, sess_hit_idx, established, now,
+    with jax.named_scope("session"):
+        established, sess_hit_idx = session_lookup_reverse_idx(
+            tables, pkts, now, shard=shard, tnt=tnt, impl=sess_impl,
+            sym=sess_hash == "sym")
+        established = established & alive
+        # pre-touch session age: an ML feature (the touch below
+        # refreshes the timestamp, so the age must be captured first —
+        # the fast tier captures it at the same pre-touch point,
+        # docs/ML_STAGE.md)
+        sess_age = session_hit_age(tables, sess_hit_idx, established,
+                                   now, shard=shard)
+        tables = session_touch(tables, sess_hit_idx, established, now,
                                shard=shard)
-    tables = session_touch(tables, sess_hit_idx, established, now,
-                           shard=shard)
 
     # --- NAT44: reverse-translate return traffic, then DNAT new flows ---
-    pkts, nat_reversed, nat_hit_idx = nat44_reverse(tables, pkts, alive,
-                                                    now, shard=shard,
-                                                    tnt=tnt)
-    tables = nat44_touch(tables, nat_hit_idx, nat_reversed, now,
-                         shard=shard)
+    with jax.named_scope("nat"):
+        pkts, nat_reversed, nat_hit_idx = nat44_reverse(
+            tables, pkts, alive, now, shard=shard, tnt=tnt)
+        tables = nat44_touch(tables, nat_hit_idx, nat_reversed, now,
+                             shard=shard)
 
     # --- per-packet ML scoring (ISSUE 10): on the post-reverse header,
     # the same values the fast tier scores — ONE shared evaluation
@@ -640,28 +671,32 @@ def pipeline_step(
         shard=shard, tid=tid)
 
     orig_dst, orig_dport = pkts.dst_ip, pkts.dport
-    pkts, dnat_applied, dnat_self_snat = nat44_dnat(
-        tables, pkts, alive & ~nat_reversed
-    )
+    with jax.named_scope("nat"):
+        pkts, dnat_applied, dnat_self_snat = nat44_dnat(
+            tables, pkts, alive & ~nat_reversed
+        )
 
     # --- ACL classify (local per-interface table + node-global table) ---
-    local_v = acl_local_fn(tables, pkts)
-    glob_v = acl_global_fn(tables, pkts)
-    permit = (local_v.permit & glob_v.permit) | established
-    drop_acl = alive & ~permit
+    with jax.named_scope("classify"):
+        local_v = acl_local_fn(tables, pkts)
+        glob_v = acl_global_fn(tables, pkts)
+        permit = (local_v.permit & glob_v.permit) | established
+        drop_acl = alive & ~permit
 
-    # enforce-mode ML verdict, folded AFTER the ACL verdict: an
-    # ACL-denied packet stays an ACL drop (deny beats ml-drop), an
-    # ACL-permitted flagged packet drops here (ml-drop beats permit)
-    ml_dropped = ml_drop_want & permit & alive
+        # enforce-mode ML verdict, folded AFTER the ACL verdict: an
+        # ACL-denied packet stays an ACL drop (deny beats ml-drop), an
+        # ACL-permitted flagged packet drops here (ml-drop beats permit)
+        ml_dropped = ml_drop_want & permit & alive
 
     # --- ip4-lookup (on possibly NAT-rewritten dst; dense or LPM per
     # the fib_impl ladder — both resolve through ops.fib) ---
-    fib = fib_fn(tables, pkts)
-    forwarded = (alive & permit & ~ml_dropped & fib.matched
-                 & (fib.disp != int(Disposition.DROP)))
-    disp = jnp.where(forwarded, fib.disp, int(Disposition.DROP)).astype(jnp.int32)
-    tx_if = jnp.where(forwarded, fib.tx_if, -1)
+    with jax.named_scope("fib"):
+        fib = fib_fn(tables, pkts)
+        forwarded = (alive & permit & ~ml_dropped & fib.matched
+                     & (fib.disp != int(Disposition.DROP)))
+        disp = jnp.where(forwarded, fib.disp,
+                         int(Disposition.DROP)).astype(jnp.int32)
+        tx_if = jnp.where(forwarded, fib.tx_if, -1)
 
     # --- SNAT for cluster-egress flows (routes marked snat) and for
     # self-snat DNAT mappings (nodeports: the backend's reply must return
@@ -669,40 +704,49 @@ def pipeline_step(
     # New outbound flows only: reply traffic (un-NAT'd above, or admitted
     # via a reflective session) must keep its translated/original source.
     # Reference: configurator_impl.go:258-264 SNAT pool.
-    is_l4 = (pkts.proto == 6) | (pkts.proto == 17)
-    nat_capable = is_l4 | (pkts.proto == 1)  # icmp: src-only translation
-    fresh = ~nat_reversed & ~established
-    orig_src, orig_sport = pkts.src_ip, pkts.sport
-    want_snat = forwarded & fresh & nat_capable & (fib.snat | dnat_self_snat)
-    pkts, snat_applied = nat44_snat(tables, pkts, want_snat)
-    # A protocol NAT can't translate, leaving via an SNAT route, would
-    # leak the pod's private source address — fail closed.
-    nat_unsupported = (
-        forwarded & fresh & ~nat_capable & fib.snat
-        & (tables.nat_snat_ip != 0)
-    )
+    with jax.named_scope("nat"):
+        is_l4 = (pkts.proto == 6) | (pkts.proto == 17)
+        # icmp: src-only translation
+        nat_capable = is_l4 | (pkts.proto == 1)
+        fresh = ~nat_reversed & ~established
+        orig_src, orig_sport = pkts.src_ip, pkts.sport
+        want_snat = (forwarded & fresh & nat_capable
+                     & (fib.snat | dnat_self_snat))
+        pkts, snat_applied = nat44_snat(tables, pkts, want_snat)
+        # A protocol NAT can't translate, leaving via an SNAT route,
+        # would leak the pod's private source address — fail closed.
+        nat_unsupported = (
+            forwarded & fresh & ~nat_capable & fib.snat
+            & (tables.nat_snat_ip != 0)
+        )
 
     # --- session install for newly permitted flows only (denied packets
     # must not consume session slots); keys are post-NAT so replies match ---
-    want_sess = forwarded & ~established & nat_capable & ~nat_unsupported
-    tables, _, sess_fail, sess_ev_exp, sess_ev_vic = session_insert(
-        tables, pkts, want_sess, now, shard=shard, tnt=tnt,
-        sym=sess_hash == "sym")
-    nat_kind = (
-        jnp.where(dnat_applied, 1, 0) + jnp.where(snat_applied, 2, 0)
-    ).astype(jnp.int32)
-    tables, nat_conflict, natsess_fail, nat_ev_exp, nat_ev_vic = nat44_record(
-        tables, pkts, orig_dst, orig_dport, orig_src, orig_sport, nat_kind,
-        (dnat_applied | snat_applied) & forwarded, now, shard=shard,
-        tnt=tnt,
-    )
-    # Fail closed on reply-key collisions (two SNAT'd flows hashed onto
-    # the same external port): misdelivering replies to the wrong pod is
-    # worse than dropping the colliding flow — drops are counted.
-    dropped_nat = nat_conflict | nat_unsupported
-    forwarded = forwarded & ~dropped_nat
-    disp = jnp.where(dropped_nat, int(Disposition.DROP), disp).astype(jnp.int32)
-    tx_if = jnp.where(dropped_nat, -1, tx_if)
+    with jax.named_scope("session"):
+        want_sess = (forwarded & ~established & nat_capable
+                     & ~nat_unsupported)
+        tables, _, sess_fail, sess_ev_exp, sess_ev_vic = session_insert(
+            tables, pkts, want_sess, now, shard=shard, tnt=tnt,
+            sym=sess_hash == "sym")
+    with jax.named_scope("nat"):
+        nat_kind = (
+            jnp.where(dnat_applied, 1, 0) + jnp.where(snat_applied, 2, 0)
+        ).astype(jnp.int32)
+        (tables, nat_conflict, natsess_fail, nat_ev_exp,
+         nat_ev_vic) = nat44_record(
+            tables, pkts, orig_dst, orig_dport, orig_src, orig_sport,
+            nat_kind, (dnat_applied | snat_applied) & forwarded, now,
+            shard=shard, tnt=tnt,
+        )
+        # Fail closed on reply-key collisions (two SNAT'd flows hashed
+        # onto the same external port): misdelivering replies to the
+        # wrong pod is worse than dropping the colliding flow — drops
+        # are counted.
+        dropped_nat = nat_conflict | nat_unsupported
+        forwarded = forwarded & ~dropped_nat
+        disp = jnp.where(dropped_nat, int(Disposition.DROP),
+                         disp).astype(jnp.int32)
+        tx_if = jnp.where(dropped_nat, -1, tx_if)
 
     # counters / attribution / result assembly: the shared tail
     return _finish_step(
@@ -784,12 +828,14 @@ def _pipeline_fast_finish(
     if tnt_dropped is None:
         tnt_dropped = jnp.zeros(alive.shape, bool)
     # pre-touch session age (the ML age feature — full-chain parity)
-    sess_age = session_hit_age(tables, sess_hit_idx, established, now,
+    with jax.named_scope("session"):
+        sess_age = session_hit_age(tables, sess_hit_idx, established, now,
+                                   shard=shard)
+        tables = session_touch(tables, sess_hit_idx, established, now,
                                shard=shard)
-    tables = session_touch(tables, sess_hit_idx, established, now,
-                           shard=shard)
-    tables = nat44_touch(tables, nat_hit_idx, nat_reversed, now,
-                         shard=shard)
+    with jax.named_scope("nat"):
+        tables = nat44_touch(tables, nat_hit_idx, nat_reversed, now,
+                             shard=shard)
 
     # permit == (local & glob) | established on every alive packet by
     # the dispatch invariant, so the classify is skipped outright
@@ -799,16 +845,16 @@ def _pipeline_fast_finish(
     ml_scored, ml_flagged, ml_drop_want, ml_scores = _ml_eval(
         tables, pkts, alive, established, sess_age, ml_mode, ml_kind,
         shard=shard, tid=tid)
-    ml_dropped = ml_drop_want & permit & alive
 
-    fib = fib_fn(tables, pkts)
-    forwarded = alive & permit & ~ml_dropped & fib.matched & (
-        fib.disp != int(Disposition.DROP)
-    )
-    disp = jnp.where(forwarded, fib.disp, int(Disposition.DROP)).astype(
-        jnp.int32
-    )
-    tx_if = jnp.where(forwarded, fib.tx_if, -1)
+    with jax.named_scope("fib"):
+        ml_dropped = ml_drop_want & permit & alive
+        fib = fib_fn(tables, pkts)
+        forwarded = alive & permit & ~ml_dropped & fib.matched & (
+            fib.disp != int(Disposition.DROP)
+        )
+        disp = jnp.where(forwarded, fib.disp,
+                         int(Disposition.DROP)).astype(jnp.int32)
+        tx_if = jnp.where(forwarded, fib.tx_if, -1)
 
     # the elided stages are statically empty under the invariant: hand
     # the shared tail all-False masks (XLA folds the dead reductions)
@@ -861,8 +907,9 @@ def pipeline_step_fast(
     if overlay != "off":
         from vpp_tpu.ops.vxlan import vxlan_decap_step
 
-        pkts, ovl_bad, ovl_decapped, ovl_tid = vxlan_decap_step(
-            tables, pkts, ovl_inner, ovl_vni)
+        with jax.named_scope("overlay"):
+            pkts, ovl_bad, ovl_decapped, ovl_tid = vxlan_decap_step(
+                tables, pkts, ovl_inner, ovl_vni)
     else:
         ovl_bad = ovl_decapped = ovl_tid = None
     pkts, drop_ip4, alive = _ingress(tables, pkts)
@@ -879,13 +926,14 @@ def pipeline_step_fast(
                                             ovl_decapped=ovl_decapped)
     alive = alive & ~tnt_dropped
     tnt = tnt_mode != "off"
-    established, sess_hit_idx = session_lookup_reverse_idx(
-        tables, pkts, now, shard=shard, tnt=tnt, impl=sess_impl,
-        sym=sess_hash == "sym")
-    established = established & alive
-    pkts, nat_reversed, nat_hit_idx = nat44_reverse(tables, pkts, alive,
-                                                    now, shard=shard,
-                                                    tnt=tnt)
+    with jax.named_scope("session"):
+        established, sess_hit_idx = session_lookup_reverse_idx(
+            tables, pkts, now, shard=shard, tnt=tnt, impl=sess_impl,
+            sym=sess_hash == "sym")
+        established = established & alive
+    with jax.named_scope("nat"):
+        pkts, nat_reversed, nat_hit_idx = nat44_reverse(
+            tables, pkts, alive, now, shard=shard, tnt=tnt)
     return _pipeline_fast_finish(
         tables, pkts, now, alive, drop_ip4, established, sess_hit_idx,
         nat_reversed, nat_hit_idx, sweep_stride=sweep_stride,
@@ -962,8 +1010,9 @@ def pipeline_step_auto(
     if overlay != "off":
         from vpp_tpu.ops.vxlan import vxlan_decap_step
 
-        pkts, ovl_bad, ovl_decapped, ovl_tid = vxlan_decap_step(
-            tables, pkts, ovl_inner, ovl_vni)
+        with jax.named_scope("overlay"):
+            pkts, ovl_bad, ovl_decapped, ovl_tid = vxlan_decap_step(
+                tables, pkts, ovl_inner, ovl_vni)
     else:
         ovl_bad = ovl_decapped = ovl_tid = None
     pkts1, drop_ip4, alive = _ingress(tables, pkts)
@@ -980,17 +1029,19 @@ def pipeline_step_auto(
                                          ovl_decapped=ovl_decapped)
     alive = alive & ~tnt_dropped
     tnt = tnt_mode != "off"
-    hits, sess_hit_idx, all_hit = session_batch_summary(
-        tbl, pkts1, alive, now, shard=shard, tnt=tnt, impl=sess_impl,
-        sym=sess_hash == "sym"
-    )
+    with jax.named_scope("session"):
+        hits, sess_hit_idx, all_hit = session_batch_summary(
+            tbl, pkts1, alive, now, shard=shard, tnt=tnt, impl=sess_impl,
+            sym=sess_hash == "sym"
+        )
     # NAT reverse runs before the DNAT probe: the un-NAT'd header is
     # what the full chain would hand nat44_dnat
-    rpkts, nat_reversed, nat_hit_idx = nat44_reverse(
-        tbl, pkts1, alive, now, shard=shard, tnt=tnt
-    )
-    dnat_would = nat44_dnat_match(tbl, rpkts, alive & ~nat_reversed)
-    ok = all_hit & ~jnp.any(dnat_would)
+    with jax.named_scope("nat"):
+        rpkts, nat_reversed, nat_hit_idx = nat44_reverse(
+            tbl, pkts1, alive, now, shard=shard, tnt=tnt
+        )
+        dnat_would = nat44_dnat_match(tbl, rpkts, alive & ~nat_reversed)
+        ok = all_hit & ~jnp.any(dnat_would)
     if shard is not None:
         # the all-reduce that makes the dispatch provably uniform: the
         # inputs are already replicated (psum'd lookups), and the pmin

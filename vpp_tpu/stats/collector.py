@@ -176,12 +176,22 @@ PUMP_DROP_REASONS = (
 # vpp_tpu_pump_stage_seconds counter family. fetch_wait is the wait
 # for a device result to become READY (overlapped across the in-flight
 # window — not a serial path cost); fetch is the serial result copy.
+# dp_upload/dp_call split the dispatch call (the dataplane's argument
+# upload, its jitted step call) and dispatch_cpu is the dispatch
+# thread's CPU time over it; fetch_queue and reorder_wait are the waits
+# between stages (hand-off queue to a fetch worker, fetched result to
+# the tx writer).
 PUMP_STAGE_SECONDS = (
     ("t_pack", "pack"),
     ("t_dispatch", "dispatch"),
     ("t_fetch_wait", "fetch_wait"),
     ("t_fetch", "fetch"),
     ("t_write", "write"),
+    ("t_fetch_queue", "fetch_queue"),
+    ("t_reorder_wait", "reorder_wait"),
+    ("t_dp_upload", "dp_upload"),
+    ("t_dp_call", "dp_call"),
+    ("t_dispatch_cpu", "dispatch_cpu"),
 )
 
 # Global-classify implementations the vpp_tpu_acl_classifier info

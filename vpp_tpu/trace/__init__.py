@@ -1,10 +1,11 @@
 """Tracing & profiling.
 
 Reference analogs: the VPP packet tracer (`trace add <node> N` + `show
-trace`, docs/VPP_PACKET_TRACING_K8S.md:20-50), per-graph-node cycle
-accounting (`show run` clocks/vector, :28-50), and — new in the
-control-plane observability layer — span tracing over the config path
-(``vpp_tpu.trace.spans``).
+trace`, docs/VPP_PACKET_TRACING_K8S.md:20-50), span tracing over the
+config path (``vpp_tpu.trace.spans``), and the served path's host
+stage timer (``vpp_tpu.trace.timed``), whose profiler spans sit on
+the device trace's clock next to the fused step's per-stage
+``jax.named_scope``s — the `show run` analog (:28-50) under XLA.
 
 Re-exports resolve lazily (PEP 562): the packet tracer pulls in the
 jax-backed pipeline, and light processes (kvserver, KSR) that only need
@@ -14,9 +15,6 @@ jax-backed pipeline, and light processes (kvserver, KSR) that only need
 _LAZY = {
     "PacketTracer": ("vpp_tpu.trace.tracer", "PacketTracer"),
     "TraceEntry": ("vpp_tpu.trace.tracer", "TraceEntry"),
-    "StageTiming": ("vpp_tpu.trace.cycles", "StageTiming"),
-    "profile_stages": ("vpp_tpu.trace.cycles", "profile_stages"),
-    "format_show_run": ("vpp_tpu.trace.cycles", "format_show_run"),
     "Span": ("vpp_tpu.trace.spans", "Span"),
     "SpanTracer": ("vpp_tpu.trace.spans", "SpanTracer"),
 }
