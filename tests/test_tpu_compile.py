@@ -181,3 +181,49 @@ def test_step_with_every_pallas_rung_compiles(compile_for, monkeypatch):
                               sess_impl=dp.session_impl)
     txt = compile_for(step, dp.tables, pkts, jnp.int32(1))
     assert txt.count("tpu_custom_call") >= 3
+
+
+def test_acl_local_bv_first_set_compiles(compile_for):
+    """The local tables' first-set kernel at the namespaced-egress
+    node's widths (10,272-rule tables: 321 words), under its own name."""
+    from vpp_tpu.ops.acl_bv import acl_local_bv_first_set
+
+    txt = compile_for(acl_local_bv_first_set,
+                      *[S((2048, 321), jnp.uint32)] * 5)
+    assert "%acl_local_bv_first_set" in txt
+
+
+def test_local_only_step_serves_the_local_kernel(compile_for, monkeypatch):
+    """A node whose only policy sits in a local table: auto selects the
+    pallas rung from the local table's size, and the step's local
+    classify runs the local kernel (no global one: the table is empty)."""
+    import vpp_tpu.ops._pallas as pallas_mod
+    from vpp_tpu.ir.rule import Action, ContivRule, Protocol
+    from vpp_tpu.pipeline.dataplane import Dataplane
+    from vpp_tpu.pipeline.graph import make_pipeline_step
+    from vpp_tpu.pipeline.tables import DataplaneConfig
+    from vpp_tpu.pipeline.vector import Disposition, make_packet_vector
+
+    monkeypatch.setattr(pallas_mod, "use_pallas", lambda: True)
+    dp = Dataplane(DataplaneConfig(max_tables=2, max_rules=256,
+                                   classifier_bv_min_rules=128))
+    pod = dp.add_pod_interface(("ns", "pod"))
+    dp.builder.add_route("10.9.0.2/32", pod, Disposition.LOCAL)
+    slot = dp.alloc_table_slot("T")
+    dp.builder.set_local_table(slot, [
+        ContivRule(action=Action.PERMIT, protocol=Protocol.TCP,
+                   dest_port=1000 + i) for i in range(200)]
+        + [ContivRule(action=Action.DENY, protocol=Protocol.TCP)])
+    dp.assign_pod_table(("ns", "pod"), "T")
+    dp.swap()
+    assert dp.builder.glb_nrules == 0
+    assert dp.kernel_snapshot()["classifier"]["impl"] == "pallas"
+    pkts = make_packet_vector([{"src": "10.9.0.2", "dst": "10.9.0.3",
+                                "proto": 6, "sport": 1000, "dport": 1001,
+                                "rx_if": pod}])
+    step = make_pipeline_step(dp.classifier_impl, dp._skip_local,
+                              fast=dp._use_fastpath,
+                              fib_impl=dp.fib_impl,
+                              sess_impl=dp.session_impl)
+    txt = compile_for(step, dp.tables, pkts, jnp.int32(1))
+    assert "%acl_local_bv_first_set" in txt

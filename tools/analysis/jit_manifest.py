@@ -59,6 +59,10 @@ JIT_SITES = {
         "pallas BV word-AND + first-set-bit kernel entry (ISSUE 16); "
         "static interpret flag only — the fused rung gathers segment "
         "rows on-device and reduces them in VMEM tiles",
+    ("vpp_tpu/ops/acl_bv.py", "@acl_local_bv_first_set"):
+        "the same pallas kernel for the per-interface local tables, "
+        "under its own kernel name so a device trace times it apart; "
+        "static interpret flag only",
     ("vpp_tpu/ops/lpm.py", "@lpm_fused_lookup"):
         "pallas LPM kernel entry (ISSUE 16): VMEM-resident planes, "
         "a compare-scan of each populated length's live entries, "
@@ -209,10 +213,16 @@ PALLAS_KERNELS = {
             "glb_bv_bnd_dport", "glb_bv_nbnd", "glb_bv_src",
             "glb_bv_dst", "glb_bv_sport", "glb_bv_dport",
             "glb_bv_proto",
+        ),
+    },
+    ("vpp_tpu/ops/acl_bv.py", "@acl_local_bv_first_set"): {
+        "fn": "acl_classify_local_pallas",
+        "knob": "classifier",
+        "fields": (
             "acl_bv_bnd_src", "acl_bv_bnd_dst", "acl_bv_bnd_sport",
             "acl_bv_bnd_dport", "acl_bv_nbnd", "acl_bv_src",
             "acl_bv_dst", "acl_bv_sport", "acl_bv_dport",
-            "acl_bv_proto",
+            "acl_bv_proto", "if_local_table",
         ),
     },
     ("vpp_tpu/ops/lpm.py", "@lpm_fused_lookup"): {

@@ -283,6 +283,9 @@ class DataplanePump:
         self.workers = max(1, int(workers))
         self.stats = {
             "frames": 0, "pkts": 0, "batches": 0, "tx_ring_full": 0,
+            # packets dispatched whose rx interface points at a local
+            # ACL table (dp.host_counters, folded per dispatch)
+            "local_table_pkts": 0,
             "max_coalesce": 0, "batch_errors": 0,
             # cumulative seconds per stage (profiling; `show io` /
             # bench read these to attribute wire-path time). t_fetch
@@ -1211,8 +1214,10 @@ class DataplanePump:
                 for k, g in enumerate(groups):
                     self._pack_group(g, flat[k], non_ip[k])
             non_ip = non_ip.view(bool)
-        timers = getattr(self.dp, "host_timers", None)
-        timers0 = dict(timers) if timers is not None else {}
+        host = [d for d in (getattr(self.dp, "host_timers", None),
+                            getattr(self.dp, "host_counters", None))
+                if d is not None]
+        host0 = [dict(d) for d in host]
         with Timed("pump.dispatch", self.stats, "t_dispatch",
                    cpu_key="t_dispatch_cpu") as disp:
             if slow:
@@ -1235,8 +1240,9 @@ class DataplanePump:
                 self.stats["chain_batches"] += 1
                 self.stats["chain_k_peak"] = max(
                     self.stats["chain_k_peak"], K)
-        for k, v in timers0.items():
-            self.stats[k] += timers[k] - v
+        for d, d0 in zip(host, host0):
+            for k, v in d0.items():
+                self.stats[k] += d[k] - v
         t0 = disp.t0
         # unlocked: the dispatch thread is _seq's only writer, so its
         # own read needs no lock; increments publish under _done_cv.

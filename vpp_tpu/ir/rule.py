@@ -13,6 +13,7 @@ Re-designed for Python: networks are ``ipaddress.IPv4Network`` /
 from __future__ import annotations
 
 import enum
+import functools
 import ipaddress
 from dataclasses import dataclass
 from typing import List, NamedTuple, Optional, Union
@@ -98,10 +99,36 @@ class ContivRule:
 
     # Total order (see compare_rules); enables `sorted(rules)`.
     def __lt__(self, other: "ContivRule") -> bool:
-        return compare_rules(self, other) < 0
+        return self.sort_key < other.sort_key
+
+    @functools.cached_property
+    def sort_key(self) -> tuple:
+        """The key of the rules' total order: if a matches a subset of
+        b's traffic, ``a.sort_key < b.sort_key``; equal keys are equal
+        rules. Order of significance: protocol, src net, dst net, src
+        port, dst port, action (reference: renderer/api.go:110-136).
+        Computed once per rule, so sorting and bisecting a 10k-rule
+        table compares tuples in C."""
+        return (int(self.protocol), _net_key(self.src_network),
+                _net_key(self.dest_network), _port_key(self.src_port),
+                _port_key(self.dest_port), int(self.action))
 
 
-def compare_ints(a: int, b: int) -> int:
+def _net_key(net: Optional[IPNetwork]) -> tuple:
+    # a ⊂ b ⇒ a first: IPv4 before IPv6, longer prefix first, then by
+    # address (a total order over disjoint subnets); None (0/0) last
+    if net is None:
+        return (2, 0, 0)
+    return (0 if net.version == 4 else 1, -net.prefixlen,
+            int(net.network_address))
+
+
+def _port_key(port: int) -> tuple:
+    # 0 (= all ports) after every specific port
+    return (1, 0) if port == ANY_PORT else (0, port)
+
+
+def compare_ints(a, b) -> int:
     return (a > b) - (a < b)
 
 
@@ -110,13 +137,7 @@ def compare_ports(a: int, b: int) -> int:
 
     Reference: plugins/policy/utils/utils.go ComparePorts.
     """
-    if a == b:
-        return 0
-    if a == ANY_PORT:
-        return 1
-    if b == ANY_PORT:
-        return -1
-    return compare_ints(a, b)
+    return compare_ints(_port_key(a), _port_key(b))
 
 
 def compare_ip_nets(a: Optional[IPNetwork], b: Optional[IPNetwork]) -> int:
@@ -124,55 +145,20 @@ def compare_ip_nets(a: Optional[IPNetwork], b: Optional[IPNetwork]) -> int:
 
     Reference: plugins/policy/utils/utils.go CompareIPNets.
     """
-    if a is None:
-        return 0 if b is None else 1
-    if b is None:
-        return -1
-
-    # IPv4 sorts before IPv6.
-    a4, b4 = a.version == 4, b.version == 4
-    if a4 != b4:
-        return -1 if a4 else 1
-
-    # Same common prefix => longer (more specific) prefix sorts first.
-    common = min(a.prefixlen, b.prefixlen)
-    a_net = int(a.network_address) >> (a.max_prefixlen - common) if common else 0
-    b_net = int(b.network_address) >> (b.max_prefixlen - common) if common else 0
-    if a_net == b_net:
-        return compare_ints(b.prefixlen, a.prefixlen)
-
-    # Disjoint subnets: arbitrary but total order (by mask desc, then address).
-    mask_order = compare_ints(b.prefixlen, a.prefixlen)
-    if mask_order != 0:
-        return mask_order
-    return compare_ints(int(a.network_address), int(b.network_address))
+    return compare_ints(_net_key(a), _net_key(b))
 
 
 def compare_rules(a: ContivRule, b: ContivRule) -> int:
-    """Total order over rules: if a matches a subset of b's traffic, a < b.
-
-    Order of significance: protocol, src net, dst net, src port, dst port,
-    action. Reference: renderer/api.go:110-136.
-    """
-    for cmp in (
-        compare_ints(int(a.protocol), int(b.protocol)),
-        compare_ip_nets(a.src_network, b.src_network),
-        compare_ip_nets(a.dest_network, b.dest_network),
-        compare_ports(a.src_port, b.src_port),
-        compare_ports(a.dest_port, b.dest_port),
-    ):
-        if cmp != 0:
-            return cmp
-    return compare_ints(int(a.action), int(b.action))
+    """Total order over rules (``ContivRule.sort_key``): if a matches a
+    subset of b's traffic, a < b."""
+    return compare_ints(a.sort_key, b.sort_key)
 
 
 def compare_rule_lists(a: List[ContivRule], b: List[ContivRule]) -> int:
-    """Lexicographic order over sorted rule lists (used for table dedup)."""
-    for ra, rb in zip(a, b):
-        cmp = compare_rules(ra, rb)
-        if cmp != 0:
-            return cmp
-    return compare_ints(len(a), len(b))
+    """Lexicographic order over sorted rule lists (used for table dedup):
+    one list comparison, which skips shared rule objects by identity and
+    orders the first differing pair by ``sort_key``."""
+    return compare_ints(a, b)
 
 
 def allow_all_tcp() -> ContivRule:

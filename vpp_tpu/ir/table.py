@@ -12,9 +12,24 @@ from __future__ import annotations
 
 import bisect
 import enum
-from typing import Callable, List, Optional, Set
+import operator
+from typing import Iterable, List, Optional, Set
 
-from vpp_tpu.ir.rule import ContivRule, PodID, compare_rules
+from vpp_tpu.ir.rule import ContivRule, PodID
+
+
+def sorted_unique(rules: Iterable[ContivRule]) -> List[ContivRule]:
+    """``rules`` in table order with duplicates dropped, keeping the
+    first of equal rules: the list ``insert_rule`` builds one rule at a
+    time, in one sort."""
+    out: List[ContivRule] = []
+    last = None
+    for r in sorted(rules, key=operator.attrgetter("sort_key")):
+        if r.sort_key != last:
+            out.append(r)
+            last = r.sort_key
+    return out
+
 
 # The single node-global table is always identified by this ID.
 GLOBAL_TABLE_ID = "NODE-GLOBAL"
@@ -50,21 +65,14 @@ class ContivRuleTable:
     def insert_rule(self, rule: ContivRule) -> bool:
         """Insert keeping sort order; returns False if already present."""
         idx = bisect.bisect_left(self.rules, rule)
-        if idx < len(self.rules) and compare_rules(self.rules[idx], rule) == 0:
+        if idx < len(self.rules) and self.rules[idx].sort_key == rule.sort_key:
             return False
         self.rules.insert(idx, rule)
         return True
 
-    def remove_by_predicate(self, pred: Callable[[ContivRule], bool]) -> int:
-        """Remove all rules matching the predicate; returns removed count."""
-        kept = [r for r in self.rules if not pred(r)]
-        removed = len(self.rules) - len(kept)
-        self.rules = kept
-        return removed
-
     def has_rule(self, rule: ContivRule) -> bool:
         idx = bisect.bisect_left(self.rules, rule)
-        return idx < len(self.rules) and compare_rules(self.rules[idx], rule) == 0
+        return idx < len(self.rules) and self.rules[idx].sort_key == rule.sort_key
 
     def copy(self) -> "ContivRuleTable":
         """Copy with independent pod set; rules list is copied (entries shared —

@@ -96,18 +96,26 @@ def bv_global_bytes(max_rules: int) -> int:
     return ib * w * 4 * 4 + pr * w * 4 + ib * 4 * 4 + 4 * 4
 
 
+def bv_config_bytes(config) -> int:
+    """Device bytes of every BV structure a config allocates: the global
+    table's and ``max_tables`` local tables' at ``max_rules``."""
+    return (bv_global_bytes(config.max_global_rules)
+            + int(config.max_tables) * bv_global_bytes(config.max_rules))
+
+
 def bv_enabled_for(config) -> bool:
     """Whether this config allocates (and commit-time builds) the BV
     structure: explicit ``classifier: bv`` always (``pallas`` rides
     the SAME planes — ISSUE 16); ``auto`` only when the worst-case
-    structure fits the ``classifier_bv_mem_mb`` cap."""
+    structures, the global one and the ``max_tables`` local ones, fit
+    the ``classifier_bv_mem_mb`` cap."""
     knob = getattr(config, "classifier", "auto")
     if knob in ("bv", "pallas"):
         return True
     if knob != "auto":
         return False
     cap_mb = int(getattr(config, "classifier_bv_mem_mb", 256))
-    return bv_global_bytes(config.max_global_rules) <= cap_mb * (1 << 20)
+    return bv_config_bytes(config) <= cap_mb * (1 << 20)
 
 
 class BvTable(NamedTuple):
@@ -354,32 +362,50 @@ def acl_classify_global_bv(tables, pkts: PacketVector) -> AclVerdict:
     return assemble_global_verdict(tables, pkts, matched, act == 1, rule)
 
 
-def acl_classify_local_bv(tables, pkts: PacketVector) -> AclVerdict:
-    """acl_classify_local on the BV path: each packet looks up its rx
-    interface's local table planes — per-packet boundary rows are
-    gathered and the binary search vmapped, so the whole frame still
-    classifies in one dense op. Unlike the MXU path (global-only),
-    this serves the per-interface tables too."""
+def _local_segment_of(bnd: jnp.ndarray, t: jnp.ndarray, vals: jnp.ndarray,
+                      n: jnp.ndarray) -> jnp.ndarray:
+    """``_segment_of`` of each packet in ITS table's boundaries: a
+    vectorised binary search over the flattened [T * I] boundary array
+    that gathers only the probed element per packet and step (log2(I)
+    steps), where searching gathered [P, I] rows would read every
+    packet's whole row. The live prefix [0, n) holds every boundary at
+    or below a real value (pads sort above), so the count it gives,
+    minus one and clipped, equals ``searchsorted`` over the whole row."""
+    width = bnd.shape[1]
+    flat = bnd.reshape(-1)
+    dt = jnp.promote_types(bnd.dtype, vals.dtype)
+    v = vals.astype(dt)
+    base = t * width
+    lo = jnp.zeros_like(n)
+    hi = n
+    for _ in range(max(1, int(width).bit_length())):
+        live = lo < hi
+        mid = (lo + hi) >> 1
+        le = flat[base + jnp.where(live, mid, 0)].astype(dt) <= v
+        lo = jnp.where(live & le, mid + 1, lo)
+        hi = jnp.where(live & ~le, mid, hi)
+    return jnp.clip(lo - 1, 0, n - 1)
+
+
+def _local_rows(tables, pkts: PacketVector):
+    """(table slot [P], has_table [P], the five bitmap rows [P, W]) of
+    each packet's rx-interface local table."""
     tid = tables.if_local_table[pkts.rx_if]
-    has_table = tid >= 0
     t = jnp.maximum(tid, 0)
     nb = tables.acl_bv_nbnd[t]  # [P, 4]
-
-    def seg(bnd_rows, vals, n):
-        i = jax.vmap(
-            lambda b, v: jnp.searchsorted(b, v, side="right")
-        )(bnd_rows, vals).astype(jnp.int32) - 1
-        return jnp.clip(i, 0, n - 1)
-
-    si = seg(tables.acl_bv_bnd_src[t], pkts.src_ip, nb[:, 0])
-    di = seg(tables.acl_bv_bnd_dst[t], pkts.dst_ip, nb[:, 1])
-    pi = seg(tables.acl_bv_bnd_sport[t], pkts.sport, nb[:, 2])
-    qi = seg(tables.acl_bv_bnd_dport[t], pkts.dport, nb[:, 3])
+    si = _local_segment_of(tables.acl_bv_bnd_src, t, pkts.src_ip, nb[:, 0])
+    di = _local_segment_of(tables.acl_bv_bnd_dst, t, pkts.dst_ip, nb[:, 1])
+    pi = _local_segment_of(tables.acl_bv_bnd_sport, t, pkts.sport, nb[:, 2])
+    qi = _local_segment_of(tables.acl_bv_bnd_dport, t, pkts.dport, nb[:, 3])
     pr = jnp.clip(pkts.proto, 0, tables.acl_bv_proto.shape[1] - 1)
-    words = (tables.acl_bv_src[t, si] & tables.acl_bv_dst[t, di]
-             & tables.acl_bv_sport[t, pi] & tables.acl_bv_dport[t, qi]
-             & tables.acl_bv_proto[t, pr])
-    matched, rule = _first_set_bit(words)
+    rows = (tables.acl_bv_src[t, si], tables.acl_bv_dst[t, di],
+            tables.acl_bv_sport[t, pi], tables.acl_bv_dport[t, qi],
+            tables.acl_bv_proto[t, pr])
+    return t, tid >= 0, rows
+
+
+def _local_verdict(tables, pkts: PacketVector, t, has_table, matched,
+                   rule) -> AclVerdict:
     safe = jnp.where(matched, rule, 0)
     act = tables.acl_action[t, safe]
     permit = jnp.where(
@@ -389,6 +415,18 @@ def acl_classify_local_bv(tables, pkts: PacketVector) -> AclVerdict:
         permit=jnp.where(has_table, permit, True),
         rule_idx=jnp.where(has_table & matched, rule, -1),
     )
+
+
+def acl_classify_local_bv(tables, pkts: PacketVector) -> AclVerdict:
+    """acl_classify_local on the BV path: each packet looks up its rx
+    interface's local table planes (a binary search per dimension in
+    its own table's boundaries, then one bitmap row per dimension), so
+    the whole frame still classifies in one dense op. Unlike the MXU
+    path (global-only), this serves the per-interface tables too."""
+    t, has_table, rows = _local_rows(tables, pkts)
+    src, dst, sport, dport, proto = rows
+    matched, rule = _first_set_bit(src & dst & sport & dport & proto)
+    return _local_verdict(tables, pkts, t, has_table, matched, rule)
 
 
 # --- pallas rung (ISSUE 16) -------------------------------------------
@@ -458,6 +496,24 @@ def bv_first_set(rows_src: jnp.ndarray, rows_dst: jnp.ndarray,
     suite (tests/test_pallas_kernels.py) holds the two together.
     P and W are padded to tile multiples here; zero pad words can
     never produce a candidate."""
+    return _first_set_call("bv_first_set", interpret, rows_src, rows_dst,
+                           rows_sport, rows_dport, rows_proto)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def acl_local_bv_first_set(rows_src: jnp.ndarray, rows_dst: jnp.ndarray,
+                           rows_sport: jnp.ndarray, rows_dport: jnp.ndarray,
+                           rows_proto: jnp.ndarray,
+                           interpret: bool = False) -> jnp.ndarray:
+    """``bv_first_set`` for the local tables' rows, under its own kernel
+    name, so a device trace times the local classify apart from the
+    global one."""
+    return _first_set_call("acl_local_bv_first_set", interpret, rows_src,
+                           rows_dst, rows_sport, rows_dport, rows_proto)
+
+
+def _first_set_call(name: str, interpret: bool, rows_src, rows_dst,
+                    rows_sport, rows_dport, rows_proto) -> jnp.ndarray:
     from vpp_tpu.ops._pallas import get_pallas
 
     pl, pltpu = get_pallas("bv_first_set")
@@ -481,6 +537,7 @@ def bv_first_set(rows_src: jnp.ndarray, rows_dst: jnp.ndarray,
                                memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((p_pad, 1), jnp.int32),
         interpret=interpret,
+        name=name,
         cost_estimate=pl.CostEstimate(
             flops=9 * p_pad * w_pad,
             bytes_accessed=5 * p_pad * w_pad * 4 + p_pad * 4,
@@ -537,43 +594,17 @@ def acl_classify_global_pallas(tables, pkts: PacketVector) -> AclVerdict:
 
 
 def acl_classify_local_pallas(tables, pkts: PacketVector) -> AclVerdict:
-    """The "pallas" rung's local classify: the per-interface plane
-    gathers stay XLA (they are [P]-indexed slices of the [T, ...] BV
-    planes), the word-AND + priority encode runs in the SAME fused
-    kernel as the global path. Falls back to acl_classify_local_bv
-    off-TPU — bit-exact (identical gathered rows, identical encode)."""
+    """The "pallas" rung's local classify: the per-packet searches and
+    row gathers stay XLA, the word-AND + priority encode runs in the
+    fused kernel, under its own name (``acl_local_bv_first_set``).
+    Falls back to acl_classify_local_bv off-TPU — bit-exact (identical
+    gathered rows, identical encode)."""
     from vpp_tpu.ops._pallas import use_pallas
 
     if not use_pallas():
         return acl_classify_local_bv(tables, pkts)
-    tid = tables.if_local_table[pkts.rx_if]
-    has_table = tid >= 0
-    t = jnp.maximum(tid, 0)
-    nb = tables.acl_bv_nbnd[t]  # [P, 4]
-
-    def seg(bnd_rows, vals, n):
-        i = jax.vmap(
-            lambda b, v: jnp.searchsorted(b, v, side="right")
-        )(bnd_rows, vals).astype(jnp.int32) - 1
-        return jnp.clip(i, 0, n - 1)
-
-    si = seg(tables.acl_bv_bnd_src[t], pkts.src_ip, nb[:, 0])
-    di = seg(tables.acl_bv_bnd_dst[t], pkts.dst_ip, nb[:, 1])
-    pi = seg(tables.acl_bv_bnd_sport[t], pkts.sport, nb[:, 2])
-    qi = seg(tables.acl_bv_bnd_dport[t], pkts.dport, nb[:, 3])
-    pr = jnp.clip(pkts.proto, 0, tables.acl_bv_proto.shape[1] - 1)
-    enc = bv_first_set(
-        tables.acl_bv_src[t, si], tables.acl_bv_dst[t, di],
-        tables.acl_bv_sport[t, pi], tables.acl_bv_dport[t, qi],
-        tables.acl_bv_proto[t, pr])
+    t, has_table, rows = _local_rows(tables, pkts)
+    enc = acl_local_bv_first_set(*rows)
     matched = enc != BV_ENC_MISS
-    rule = jnp.where(matched, enc, -1)
-    safe = jnp.where(matched, enc, 0)
-    act = tables.acl_action[t, safe]
-    permit = jnp.where(
-        matched, act == 1, acl_unmatched_default(pkts, tables.acl_nrules[t])
-    )
-    return AclVerdict(
-        permit=jnp.where(has_table, permit, True),
-        rule_idx=jnp.where(has_table & matched, rule, -1),
-    )
+    return _local_verdict(tables, pkts, t, has_table, matched,
+                          jnp.where(matched, enc, -1))

@@ -688,9 +688,10 @@ class ClusterDataplane:
         bv_ok = self._bv_sharded and all(
             n.builder.bv_ok() for n in self.nodes)
         nmax = max(n.builder.glb_nrules for n in self.nodes)
+        lmax = max(int(n.builder.acl_nrules.max()) for n in self.nodes)
         self._impl = select_impl(
             getattr(c, "classifier", "auto"), bv_ok, mxu_ok, nmax,
-            self.bv_min_rules, self.mxu_threshold)
+            self.bv_min_rules, self.mxu_threshold, local_nrules=lmax)
         self._use_mxu = self._impl == "mxu"
         self._use_fast = bool(getattr(c, "fastpath", True)) and \
             nmax >= int(getattr(c, "fastpath_min_rules", 0))

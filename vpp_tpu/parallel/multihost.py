@@ -345,18 +345,22 @@ class MultiHostCluster:
                            for i in self.local_nodes)
         local_nroutes = max(self.nodes[i].builder.fib_route_count()
                             for i in self.local_nodes)
+        local_lmax = max(int(self.nodes[i].builder.acl_nrules.max())
+                         for i in self.local_nodes)
         flags = np.asarray(multihost_utils.process_allgather(
             np.int32([int(local_mxu_ok), int(local_bv_ok),
                       int(local_nmax), local_kind,
                       int(local_lpm_ok),
-                      int(local_nroutes)]))).reshape(-1, 6)
+                      int(local_nroutes),
+                      local_lmax]))).reshape(-1, 7)
         mxu_ok = bool(flags[:, 0].min())
         bv_ok = self._bv_sharded and bool(flags[:, 1].min())
         nmax = int(flags[:, 2].max())
+        lmax = int(flags[:, 6].max())
         c = self.config
         self._impl = select_impl(
             getattr(c, "classifier", "auto"), bv_ok, mxu_ok, nmax,
-            self.bv_min_rules, self.mxu_threshold)
+            self.bv_min_rules, self.mxu_threshold, local_nrules=lmax)
         self._use_mxu = self._impl == "mxu"
         self._use_fast = bool(getattr(c, "fastpath", True)) and \
             nmax >= int(getattr(c, "fastpath_min_rules", 0))

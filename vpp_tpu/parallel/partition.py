@@ -313,7 +313,7 @@ def partition_lint() -> List[str]:
 
 def select_impl(knob: str, bv_ok: bool, mxu_ok: bool, nrules: int,
                 bv_min_rules: int, mxu_threshold: int,
-                pallas_ok: bool = False) -> str:
+                pallas_ok: bool = False, local_nrules: int = 0) -> str:
     """The ONE classifier-selection ladder, shared by the standalone
     Dataplane, ClusterDataplane and MultiHostCluster (each resolves
     its own eligibility bits — builder state, all-nodes agreement, or
@@ -327,7 +327,10 @@ def select_impl(knob: str, bv_ok: bool, mxu_ok: bool, nrules: int,
     next rung. The pallas rung rides the BV planes, so its structural
     eligibility IS ``bv_ok`` — ``pallas_ok`` carries only the backend
     bit (default False keeps mesh callers on the proven rungs until
-    they resolve it themselves)."""
+    they resolve it themselves). ``nrules`` is the global table's rule
+    count, ``local_nrules`` the largest staged local table's: BV serves
+    both stages, so either table reaching ``bv_min_rules`` engages it;
+    MXU classifies the global table only, so only ``nrules`` gates it."""
     if knob == "dense":
         return "dense"
     if knob == "mxu":
@@ -336,7 +339,7 @@ def select_impl(knob: str, bv_ok: bool, mxu_ok: bool, nrules: int,
         if bv_ok:
             return "pallas" if (knob == "pallas" and pallas_ok) else "bv"
         return "mxu" if mxu_ok and nrules >= mxu_threshold else "dense"
-    if bv_ok and nrules >= bv_min_rules:
+    if bv_ok and max(nrules, local_nrules) >= bv_min_rules:
         return "pallas" if pallas_ok else "bv"
     if mxu_ok and nrules >= mxu_threshold:
         return "mxu"

@@ -642,6 +642,17 @@ def pack_packet_columns(fu, cols, n: int, off: int = 0) -> None:
     fu[4, off:off + n] = (u("rx_if") << 8) | (u("flags") & 0xFF)
 
 
+def local_table_pkts(flat, if_local_table: np.ndarray) -> int:
+    """Valid packets of a host packed batch ([5, B] or a [K, 5, B]
+    stack) whose rx interface points at a local ACL table (0 for a
+    device array: counting it would fetch it)."""
+    if not isinstance(flat, np.ndarray):
+        return 0
+    row = flat[..., 4, :].view(np.uint32)
+    hit = np.take(if_local_table, row >> 8, mode="clip") >= 0
+    return int(np.count_nonzero(hit & ((row & 1) == 1)))
+
+
 def unpack_packet_input(flat) -> dict:
     """Host-side inverse of ``pack_packet_columns``: decode a [5, B]
     packed input batch back into named PacketVector column arrays (the
@@ -817,6 +828,11 @@ class Dataplane:
         # device step). The pump folds the deltas of each dispatch into
         # its own stats (spans dp.upload / dp.step_call).
         self.host_timers = {"t_dp_upload": 0.0, "t_dp_call": 0.0}
+        # packets process_packed[_chain] handed to the step whose rx
+        # interface points at a local ACL table in the served epoch,
+        # counted on the host from the packed rx_if row; folded into
+        # the pump's stats the same way
+        self.host_counters = {"local_table_pkts": 0}
         # Session time base: wall-clock ticks (TICKS_PER_SEC), not frame
         # counts — aging semantics must not depend on offered load
         # (VERDICT r1 Weak #5; the reference ages on timers).
@@ -1265,7 +1281,8 @@ class Dataplane:
         return select_impl(self.classifier, b.bv_ok(),
                            b.mxu_enabled and b.glb_mxu.ok,
                            b.glb_nrules, self.bv_min_rules,
-                           self.mxu_threshold, pallas_ok=use_pallas())
+                           self.mxu_threshold, pallas_ok=use_pallas(),
+                           local_nrules=int(b.acl_nrules.max()))
 
     def _refresh_selection(self) -> None:
         """Re-gate every per-epoch compile-time choice against the
@@ -1276,6 +1293,10 @@ class Dataplane:
         self._classifier_impl = self._select_classifier()
         self._use_mxu = self._classifier_impl == "mxu"
         self._skip_local = bool((b.if_local_table < 0).all())
+        # the published epoch's interface -> local table map, for the
+        # local_table_pkts counter (None: no interface has a table)
+        self._if_local_live = (None if self._skip_local
+                               else b.if_local_table.copy())
         self._use_fastpath = (
             self.fastpath_enabled
             and b.glb_nrules >= self.fastpath_min_rules
@@ -1489,6 +1510,7 @@ class Dataplane:
                     "ClusterDataplane; process frames via cluster.step()"
                 )
             tables = self.tables
+            if_local = self._if_local_live
             step = self._get_step(self._use_fastpath, "packed")
             if commit:
                 self._steps_since_expire += 1
@@ -1507,6 +1529,9 @@ class Dataplane:
                 args += (jnp.int32(stamp_us), jnp.int32(now_us))
         with Timed("dp.step_call", timers, "t_dp_call"):
             new_tables, out, aux = step(tables, *args)
+        if if_local is not None:
+            self.host_counters["local_table_pkts"] += local_table_pkts(
+                flat, if_local)
         if commit:
             with self._lock:
                 if tables is self.tables:
@@ -1534,6 +1559,7 @@ class Dataplane:
                     "ClusterDataplane; process frames via cluster.step()"
                 )
             tables = self.tables
+            if_local = self._if_local_live
             step = self._get_step(self._use_fastpath, "chain")
             # a K-chain sweeps once per scanned sub-batch
             self._steps_since_expire += max(1, len(flats))
@@ -1556,6 +1582,9 @@ class Dataplane:
                          jnp.int32(now_us))
         with Timed("dp.step_call", timers, "t_dp_call"):
             new_tables, (outs, auxs) = step(tables, *args)
+        if if_local is not None:
+            self.host_counters["local_table_pkts"] += local_table_pkts(
+                flats, if_local)
         with self._lock:
             if tables is self.tables:
                 self.tables = new_tables

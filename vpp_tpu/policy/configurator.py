@@ -14,7 +14,8 @@ Reference: plugins/policy/configurator/configurator_impl.go.
 
 from __future__ import annotations
 
-from typing import Dict, List, Optional, Tuple
+import time
+from typing import Dict, List, Optional, Set, Tuple
 
 from vpp_tpu.ir.rule import (
     ANY_PORT,
@@ -23,7 +24,6 @@ from vpp_tpu.ir.rule import (
     IPNetwork,
     PodID,
     Protocol as RuleProtocol,
-    compare_rules,
     one_host_subnet,
 )
 from vpp_tpu.policy.cache import PolicyCache
@@ -59,6 +59,10 @@ class PolicyConfigurator:
         self.renderers: List[PolicyRendererAPI] = []
         self.parallel_commits = parallel_commits
         self._pod_ips: Dict[PodID, IPNetwork] = {}
+        # host milliseconds of the last commit (rule expansion + every
+        # renderer commit + the epoch swap): the "render" span's length,
+        # as a counter (TableBuilder.bv_build_ms is its analog)
+        self.render_ms = 0.0
 
     def register_renderer(self, renderer: PolicyRendererAPI) -> None:
         self.renderers.append(renderer)
@@ -86,12 +90,17 @@ class PolicyConfiguratorTxn:
         # "render" span: rule expansion + every renderer commit (incl.
         # the epoch swap the TPU renderer publishes) — the per-stage
         # attribution of the policy path in an applied txn's timeline
+        t0 = time.perf_counter()
         with spans.RECORDER.span(
             "render",
             "policy-resync" if self.resync else "policy-render",
             pods=len(self.config),
         ):
-            self._commit_traced()
+            try:
+                self._commit_traced()
+            finally:
+                self.configurator.render_ms = (
+                    (time.perf_counter() - t0) * 1e3)
 
     def _commit_traced(self) -> None:
         cfg = self.configurator
@@ -148,12 +157,16 @@ class PolicyConfiguratorTxn:
         self, direction: MatchType, policies: List[ContivPolicy]
     ) -> List[ContivRule]:
         rules: List[ContivRule] = []
+        seen: Set[ContivRule] = set()
         has_policy = False
         all_allowed = False
 
         def append(*new_rules: ContivRule) -> None:
+            # equal rules compare equal under compare_rules too, so the
+            # set keeps the first of each, in O(1) per rule
             for rule in new_rules:
-                if not any(compare_rules(rule, r) == 0 for r in rules):
+                if rule not in seen:
+                    seen.add(rule)
                     rules.append(rule)
 
         def permit(

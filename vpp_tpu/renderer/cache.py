@@ -19,6 +19,7 @@ in Python (sorted lists + dict indexes instead of Go slices/maps).
 
 from __future__ import annotations
 
+import bisect
 import enum
 from dataclasses import dataclass, field
 from typing import Dict, Iterable, List, Optional, Set, Tuple
@@ -34,7 +35,7 @@ from vpp_tpu.ir.rule import (
     allow_all_udp,
     compare_rule_lists,
 )
-from vpp_tpu.ir.table import GLOBAL_TABLE_ID, ContivRuleTable, TableType
+from vpp_tpu.ir.table import GLOBAL_TABLE_ID, ContivRuleTable, TableType, sorted_unique
 from vpp_tpu.renderer.api import PodConfig
 
 
@@ -78,64 +79,6 @@ def _ports_intersection(p: Set[int], p2: Set[int]) -> Set[int]:
     if ANY_PORT in p2:
         return set(p)
     return {port for port in p if port in p2}
-
-
-def _get_allowed_egress_ports(
-    src_ip: Optional[IPNetwork], egress: List[ContivRule]
-) -> Tuple[Set[int], Set[int]]:
-    """Allowed destination (TCP, UDP) ports for traffic *from* src_ip wrt.
-    the given egress rules. Reference: ports.go getAllowedEgressPorts."""
-    tcp: Set[int] = set()
-    udp: Set[int] = set()
-    has_deny = False
-    for rule in egress:
-        if rule.action == Action.DENY:
-            # Assumes the only deny rule is the default deny-all (TCP&UDP).
-            has_deny = True
-            continue
-        if (
-            rule.src_network is not None
-            and src_ip is not None
-            and src_ip.network_address not in rule.src_network
-        ):
-            continue
-        # The port algebra models TCP/UDP only; ANY contributes to both,
-        # ICMP (portless) to neither — ICMP rules are enforced directly by
-        # the data-plane tables, not by this fold.
-        if rule.protocol in (Protocol.TCP, Protocol.ANY):
-            tcp.add(rule.dest_port)
-        if rule.protocol in (Protocol.UDP, Protocol.ANY):
-            udp.add(rule.dest_port)
-    if not has_deny:
-        return set(ANY_PORTS), set(ANY_PORTS)
-    return tcp, udp
-
-
-def _get_allowed_ingress_ports(
-    dst_ip: Optional[IPNetwork], ingress: List[ContivRule]
-) -> Tuple[Set[int], Set[int]]:
-    """Allowed destination (TCP, UDP) ports for traffic *to* dst_ip wrt.
-    the given ingress rules. Reference: ports.go getAllowedIngressPorts."""
-    tcp: Set[int] = set()
-    udp: Set[int] = set()
-    has_deny = False
-    for rule in ingress:
-        if rule.action == Action.DENY:
-            has_deny = True
-            continue
-        if (
-            rule.dest_network is not None
-            and dst_ip is not None
-            and dst_ip.network_address not in rule.dest_network
-        ):
-            continue
-        if rule.protocol in (Protocol.TCP, Protocol.ANY):
-            tcp.add(rule.dest_port)
-        if rule.protocol in (Protocol.UDP, Protocol.ANY):
-            udp.add(rule.dest_port)
-    if not has_deny:
-        return set(ANY_PORTS), set(ANY_PORTS)
-    return tcp, udp
 
 
 # --- Local-table collection (reference: renderer/cache/local_tables.go) ----
@@ -424,11 +367,12 @@ class RendererCacheTxn:
 
     # -- table building (reference: cache_impl.go refreshTables et al.)
     def _refresh_tables(self) -> None:
+        fold = _Fold(self)
         for pod in self.get_all_pods() | self.get_removed_pods():
             pod_cfg = self.get_pod_config(pod)
             if pod_cfg is None:
                 continue
-            new_table = self._build_local_table(pod, pod_cfg)
+            new_table = self._build_local_table(pod, pod_cfg, fold)
 
             # Pull the pod's original table into the txn if not already there.
             orig = self.cache.local_tables.lookup_by_pod(pod)
@@ -456,117 +400,15 @@ class RendererCacheTxn:
         self._rebuild_global_table()
         self._up_to_date = True
 
-    def _build_local_table(self, dst_pod: PodID, dst_cfg: PodConfig) -> ContivRuleTable:
+    def _build_local_table(
+        self, dst_pod: PodID, dst_cfg: PodConfig, fold: "_Fold"
+    ) -> ContivRuleTable:
         table = ContivRuleTable(self.cache._generate_table_id(), TableType.LOCAL)
         table.pods.add(dst_pod)
         if dst_cfg.removed:
             return table
-
-        # Rules already in the cache orientation are copied verbatim.
-        own_rules = dst_cfg.egress if self.cache.orientation == Orientation.EGRESS else dst_cfg.ingress
-        for rule in own_rules:
-            table.insert_rule(rule)
-
-        # Combine with the opposite direction of every pod on the node.
-        for src_pod in self.get_all_pods():
-            src_cfg = self.get_pod_config(src_pod)
-            if src_cfg is not None:
-                self._install_local_rules(table, dst_cfg, src_cfg)
-
-        # Explicitly allow traffic not matched by any rule. A rule counts as
-        # "total" for its protocol only if every match dimension is
-        # wildcarded (the reference omits the src_port check because its
-        # configurator never emits src-port rules; our IR allows them, so
-        # check it — otherwise a src-port-specific permit would suppress
-        # the allow-all append and default-deny everything else).
-        if table.rules:
-            all_tcp = any(
-                r.dest_port == ANY_PORT and r.src_port == ANY_PORT
-                and r.src_network is None and r.dest_network is None
-                and r.protocol == Protocol.TCP
-                for r in table.rules
-            )
-            all_udp = any(
-                r.dest_port == ANY_PORT and r.src_port == ANY_PORT
-                and r.src_network is None and r.dest_network is None
-                and r.protocol == Protocol.UDP
-                for r in table.rules
-            )
-            if not all_tcp:
-                table.insert_rule(allow_all_tcp())
-            if not all_udp:
-                table.insert_rule(allow_all_udp())
+        table.rules = list(fold.table_rules(dst_cfg))
         return table
-
-    def _install_local_rules(
-        self, dst_table: ContivRuleTable, dst_cfg: PodConfig, src_cfg: PodConfig
-    ) -> None:
-        """Fold the opposite-direction rules of src pod into dst pod's table,
-        preserving the combined ingress∧egress semantic in one orientation."""
-        egress_oriented = self.cache.orientation == Orientation.EGRESS
-        if egress_oriented:
-            src_tcp, src_udp = _get_allowed_ingress_ports(dst_cfg.pod_ip, src_cfg.ingress)
-            dst_tcp, dst_udp = _get_allowed_egress_ports(src_cfg.pod_ip, dst_cfg.egress)
-        else:
-            src_tcp, src_udp = _get_allowed_egress_ports(dst_cfg.pod_ip, src_cfg.egress)
-            dst_tcp, dst_udp = _get_allowed_ingress_ports(src_cfg.pod_ip, dst_cfg.ingress)
-
-        if not _ports_is_subset(dst_tcp, src_tcp):
-            self._install_allowed_ports(
-                dst_table, src_cfg.pod_ip, _ports_intersection(dst_tcp, src_tcp), Protocol.TCP
-            )
-        if not _ports_is_subset(dst_udp, src_udp):
-            self._install_allowed_ports(
-                dst_table, src_cfg.pod_ip, _ports_intersection(dst_udp, src_udp), Protocol.UDP
-            )
-
-    def _install_allowed_ports(
-        self,
-        dst_table: ContivRuleTable,
-        src_pod_ip: Optional[IPNetwork],
-        allowed_ports: Set[int],
-        protocol: Protocol,
-    ) -> None:
-        egress_oriented = self.cache.orientation == Orientation.EGRESS
-
-        # Remove the rule subtree rooted at the src pod's one-host subnet.
-        def against_src_pod(rule: ContivRule) -> bool:
-            if rule.protocol != protocol:
-                return False
-            net = rule.src_network if egress_oriented else rule.dest_network
-            if net is None or src_pod_ip is None:
-                return False
-            return (
-                net.prefixlen == net.max_prefixlen
-                and net.network_address == src_pod_ip.network_address
-            )
-
-        dst_table.remove_by_predicate(against_src_pod)
-
-        # Explicit rule per allowed port + deny-the-rest.
-        for port in allowed_ports:
-            kwargs = dict(
-                action=Action.PERMIT,
-                protocol=protocol,
-                src_port=ANY_PORT,
-                dest_port=port,
-            )
-            if egress_oriented:
-                kwargs["src_network"] = src_pod_ip
-            else:
-                kwargs["dest_network"] = src_pod_ip
-            dst_table.insert_rule(ContivRule(**kwargs))
-        kwargs = dict(
-            action=Action.DENY,
-            protocol=protocol,
-            src_port=ANY_PORT,
-            dest_port=ANY_PORT,
-        )
-        if egress_oriented:
-            kwargs["src_network"] = src_pod_ip
-        else:
-            kwargs["dest_network"] = src_pod_ip
-        dst_table.insert_rule(ContivRule(**kwargs))
 
     def _rebuild_global_table(self) -> None:
         self.global_table = ContivRuleTable(GLOBAL_TABLE_ID)
@@ -599,3 +441,201 @@ class RendererCacheTxn:
         if self.global_table.num_of_rules > 0:
             self.global_table.insert_rule(allow_all_tcp())
             self.global_table.insert_rule(allow_all_udp())
+
+
+# --- The fold (reference: cache_impl.go installLocalRules) ------------------
+
+
+def _ip_key(net: IPNetwork) -> Tuple[int, int]:
+    return net.version, int(net.network_address)
+
+
+class _PortIndex:
+    """The allowed destination (TCP, UDP) ports of ONE rule list at every
+    local pod address (reference: ports.go getAllowedEgressPorts /
+    getAllowedIngressPorts): a list with no deny allows all ports; else
+    a permit counts where its peer network holds the address, and ANY
+    counts for both protocols (ICMP for neither: the tables enforce it
+    directly). Computed once per list: the rules with no peer network
+    apply everywhere, and each distinct peer network adds its ports to
+    the pod addresses it covers (found by bisecting the sorted
+    addresses), so the whole list costs O(R log P) instead of O(R) per
+    (pod, pod) pair. ``net_attr`` names the peer field (``src_network``
+    for egress rules, ``dest_network`` for ingress rules)."""
+
+    def __init__(self, rules: List[ContivRule], net_attr: str,
+                 addrs: Dict[int, List[int]]):
+        self.has_deny = any(r.action == Action.DENY for r in rules)
+        if not self.has_deny:
+            return
+        tcp: Set[int] = set()
+        udp: Set[int] = set()
+        by_net: Dict[IPNetwork, Tuple[Set[int], Set[int]]] = {}
+        for rule in rules:
+            if rule.action == Action.DENY:
+                continue
+            net = getattr(rule, net_attr)
+            t, u = (tcp, udp) if net is None else by_net.setdefault(
+                net, (set(), set()))
+            if rule.protocol in (Protocol.TCP, Protocol.ANY):
+                t.add(rule.dest_port)
+            if rule.protocol in (Protocol.UDP, Protocol.ANY):
+                u.add(rule.dest_port)
+        self.base = (tcp, udp)
+        # a pod with no address (None) meets every rule
+        self.unaddressed = (
+            tcp.union(*(t for t, _ in by_net.values())),
+            udp.union(*(u for _, u in by_net.values())),
+        )
+        self.extra: Dict[Tuple[int, int], Tuple[Set[int], Set[int]]] = {}
+        for net, (t, u) in by_net.items():
+            ints = addrs.get(net.version, [])
+            lo = bisect.bisect_left(ints, int(net.network_address))
+            hi = bisect.bisect_right(ints, int(net.broadcast_address))
+            for a in ints[lo:hi]:
+                et, eu = self.extra.setdefault((net.version, a),
+                                               (set(tcp), set(udp)))
+                et |= t
+                eu |= u
+
+    def ports(self, ip: Optional[IPNetwork]) -> Tuple[Set[int], Set[int]]:
+        """Allowed (TCP, UDP) destination ports at pod address ``ip``;
+        the caller must not mutate them."""
+        if not self.has_deny:
+            return ANY_PORTS, ANY_PORTS
+        if ip is None:
+            return self.unaddressed
+        return self.extra.get(_ip_key(ip), self.base)
+
+
+class _Fold:
+    """One txn's view of the node for ``_build_local_table``: a pod's
+    table is its own rules plus, for every pod whose opposite-direction
+    rules restrict the traffic, the pinned ports of the pair (the
+    ingress∧egress semantic in one orientation). Port indexes are built
+    once per distinct rule list and tables once per distinct (own rules,
+    pinned ports): the cost is O(R + pods) per distinct pod
+    configuration, not O(pods² × R)."""
+
+    def __init__(self, txn: "RendererCacheTxn"):
+        self.egress_oriented = txn.cache.orientation == Orientation.EGRESS
+        # own rules are in the cache orientation; their peer is the
+        # destination for INGRESS, the source for EGRESS
+        self.own_attr = "src_network" if self.egress_oriented else "dest_network"
+        self.other_attr = "dest_network" if self.egress_oriented else "src_network"
+        pods = [(pod, txn.get_pod_config(pod)) for pod in txn.get_all_pods()]
+        pods = [(pod, cfg) for pod, cfg in pods if cfg is not None]
+        addrs: Dict[int, Set[int]] = {}
+        for _, cfg in pods:
+            if cfg.pod_ip is not None:
+                addrs.setdefault(cfg.pod_ip.version, set()).add(
+                    int(cfg.pod_ip.network_address))
+        self.addrs = {v: sorted(a) for v, a in addrs.items()}
+        # key -> (the rule list, its index): holding the list keeps the
+        # ids in the key its own for the life of the txn
+        self._indexes: Dict[tuple, Tuple[List[ContivRule], _PortIndex]] = {}
+        self._tables: Dict[tuple, List[ContivRule]] = {}
+        # the pods whose opposite-direction rules deny something: only
+        # they pin ports into other pods' tables (no deny = all ports)
+        self.folders = []
+        for _, cfg in pods:
+            idx = self._index(self._other(cfg), self.other_attr)
+            if idx.has_deny:
+                self.folders.append((cfg.pod_ip, idx))
+
+    def _own(self, cfg: PodConfig) -> List[ContivRule]:
+        return cfg.egress if self.egress_oriented else cfg.ingress
+
+    def _other(self, cfg: PodConfig) -> List[ContivRule]:
+        return cfg.ingress if self.egress_oriented else cfg.egress
+
+    def _index(self, rules: List[ContivRule], net_attr: str) -> _PortIndex:
+        # pods sharing a policy set share the rule objects: key by them
+        key = (net_attr, tuple(map(id, rules)))
+        if key not in self._indexes:
+            self._indexes[key] = (rules, _PortIndex(rules, net_attr, self.addrs))
+        return self._indexes[key][1]
+
+    def table_rules(self, dst_cfg: PodConfig) -> List[ContivRule]:
+        """The rule list of a pod's local table (not removed)."""
+        own = self._own(dst_cfg)
+        own_idx = self._index(own, self.own_attr)
+        pins = []
+        for src_ip, src_idx in self.folders:
+            src_tcp, src_udp = src_idx.ports(dst_cfg.pod_ip)
+            dst_tcp, dst_udp = own_idx.ports(src_ip)
+            if not _ports_is_subset(dst_tcp, src_tcp):
+                pins.append((src_ip, Protocol.TCP,
+                             frozenset(_ports_intersection(dst_tcp, src_tcp))))
+            if not _ports_is_subset(dst_udp, src_udp):
+                pins.append((src_ip, Protocol.UDP,
+                             frozenset(_ports_intersection(dst_udp, src_udp))))
+        key = (tuple(map(id, own)), tuple(pins))
+        rules = self._tables.get(key)
+        if rules is None:
+            rules = self._tables[key] = self._fold_rules(own, pins)
+        return rules
+
+    def _fold_rules(self, own: List[ContivRule], pins: list) -> List[ContivRule]:
+        # Pinning an address removes the one-host rules at it (_pinned),
+        # those of an earlier pin of the address and protocol included:
+        # of those pins only the last one's rules stay.
+        last = {(proto, _ip_key(ip)): n
+                for n, (ip, proto, _) in enumerate(pins) if ip is not None}
+        live = [(ip, proto, ports) for n, (ip, proto, ports) in enumerate(pins)
+                if ip is None or ip.prefixlen != ip.max_prefixlen
+                or last[(proto, _ip_key(ip))] == n]
+        drop = set(last)
+        table = ContivRuleTable("", TableType.LOCAL)
+        table.rules = sorted_unique(
+            [r for r in own if not self._pinned(r, drop)]
+            + [rule for ip, proto, ports in live
+               for rule in self._pin_rules(ip, proto, ports)])
+
+        # Explicitly allow traffic not matched by any rule. A rule counts as
+        # "total" for its protocol only if every match dimension is
+        # wildcarded (the reference omits the src_port check because its
+        # configurator never emits src-port rules; our IR allows them, so
+        # check it — otherwise a src-port-specific permit would suppress
+        # the allow-all append and default-deny everything else).
+        if table.rules:
+            all_tcp = any(
+                r.dest_port == ANY_PORT and r.src_port == ANY_PORT
+                and r.src_network is None and r.dest_network is None
+                and r.protocol == Protocol.TCP
+                for r in table.rules
+            )
+            all_udp = any(
+                r.dest_port == ANY_PORT and r.src_port == ANY_PORT
+                and r.src_network is None and r.dest_network is None
+                and r.protocol == Protocol.UDP
+                for r in table.rules
+            )
+            if not all_tcp:
+                table.insert_rule(allow_all_tcp())
+            if not all_udp:
+                table.insert_rule(allow_all_udp())
+        return table.rules
+
+    def _pinned(self, rule: ContivRule, drop: Set[tuple]) -> bool:
+        """Whether a rule is one of the subtrees rooted at a pinned
+        pod's one-host subnet (for the pinned protocol)."""
+        net = rule.src_network if self.egress_oriented else rule.dest_network
+        if net is None or net.prefixlen != net.max_prefixlen:
+            return False
+        return (rule.protocol, _ip_key(net)) in drop
+
+    def _pin_rules(self, src_pod_ip: Optional[IPNetwork], protocol: Protocol,
+                   allowed_ports) -> List[ContivRule]:
+        """An explicit permit per allowed port, then deny-the-rest."""
+        peer = "src_network" if self.egress_oriented else "dest_network"
+        out = [
+            ContivRule(action=Action.PERMIT, protocol=protocol,
+                       src_port=ANY_PORT, dest_port=port,
+                       **{peer: src_pod_ip})
+            for port in allowed_ports
+        ]
+        out.append(ContivRule(action=Action.DENY, protocol=protocol,
+                              src_port=ANY_PORT, dest_port=ANY_PORT,
+                              **{peer: src_pod_ip}))
+        return out

@@ -48,6 +48,8 @@ PER_IF_GAUGES = (
 PUMP_STAT_GAUGES = (
     ("frames", "vpp_tpu_pump_frames", "tx frames written by the IO pump"),
     ("pkts", "vpp_tpu_pump_packets", "packets moved by the IO pump"),
+    ("local_table_pkts", "vpp_tpu_pump_local_table_packets",
+     "packets dispatched whose rx interface points at a local ACL table"),
     ("batches", "vpp_tpu_pump_batches",
      "device batches dispatched by the pump"),
     ("tx_ring_full", "vpp_tpu_pump_tx_ring_full",
