@@ -155,6 +155,170 @@ class TestSlowFetchOverlap:
             rings.close()
 
 
+    def test_reorder_buffer_counts_against_the_window(self):
+        """One fetch stalls while the other worker completes every
+        later batch: those park in the writer's reorder buffer, and
+        each frees a queue slot. The dispatch stage must still stop
+        at the window (queue + one per fetch worker + one at the
+        writer) instead of refilling the queue behind the stall."""
+        dp, a, _b = make_forwarding_dp()
+        rings = IORingPair(n_slots=64)
+        n_frames = 40  # ten VEC batches of 4 x 64 pkts
+        push_frames(rings, a, n_frames, per=64)
+        max_inflight, workers = 2, 2
+        pump = DataplanePump(
+            dp, rings, max_batch=VEC, fetch_workers=workers,
+            max_inflight=max_inflight,
+            fetch_delay=lambda seq: 1.0 if seq == 0 else 0.01,
+        )
+        pump.warm()
+        pump.start()
+        try:
+            got = drain(rings, n_frames)
+            assert [int(s[0]) for s, _d, _i, _n in got] == \
+                [20000 + k for k in range(n_frames)]
+            assert pump.stats["inflight_peak"] <= max_inflight + workers + 1
+            assert pump.stats["batch_errors"] == 0
+        finally:
+            assert pump.stop()
+            rings.close()
+
+
+class TestFullBatchesUnderBacklog:
+    @pytest.mark.parametrize("per, holds_back", [
+        (VEC // 8, True),    # a full batch is 8 frames of the hold cap's 28
+        (VEC // 16, False),  # 16 frames: two in flight leave no room
+    ])
+    def test_backlog_batches_by_frame_size(self, per, holds_back):
+        """Frames trickle in while every fetch is slow, so two or more
+        batches stay in flight. Where a full batch needs at most a
+        third of the ring's hold cap, the pump leaves short groups to
+        grow in the ring and dispatches full max_batch groups, not one
+        batch per arriving frame. Smaller frames could never fill one
+        while two are in flight: they are dispatched as they come and
+        the in-flight window deepens past two. Every frame is
+        delivered in order either way."""
+        import threading
+
+        dp, a, b = make_forwarding_dp()
+        rings = IORingPair(n_slots=32)  # hold cap 28
+        n_frames, full = 48, VEC // per
+        pump = DataplanePump(dp, rings, max_batch=VEC,
+                             fetch_workers=1, max_inflight=8,
+                             fetch_delay=0.1)
+        pump.warm()
+
+        def trickle():
+            codec = PacketCodec()
+            scratch = np.zeros((VEC, rings.rx.snap), np.uint8)
+            for k in range(n_frames):
+                frames = [make_frame(CLIENT_IP, SERVER_IP, proto=17,
+                                     sport=20000 + k, dport=1000 + j)
+                          for j in range(per)]
+                cols, n = codec.parse(frames, a, scratch)
+                while not rings.rx.push(cols, n, payload=scratch):
+                    time.sleep(0.001)
+                time.sleep(0.005)
+
+        pusher = threading.Thread(target=trickle, daemon=True)
+        pump.start()
+        pusher.start()
+        try:
+            got = drain(rings, n_frames)
+            pusher.join(timeout=30)
+            assert not pusher.is_alive()
+            assert [int(s[0]) for s, _d, _i, _n in got] == \
+                [20000 + k for k in range(n_frames)]
+            assert all((i == b).all() for _s, _d, i, _n in got)
+            if holds_back:
+                # at most two short batches open the run (nothing in
+                # flight yet) and one may close it; the rest are full
+                assert pump.stats["batches"] <= 3 + n_frames // full
+                assert pump.stats["max_coalesce"] == full
+            else:
+                assert pump.stats["inflight_peak"] > 2
+            assert pump.stats["batch_errors"] == 0
+        finally:
+            assert pump.stop()
+            rings.close()
+
+
+    def test_refilled_ring_is_taken_beside_the_window(self):
+        """Under saturation a freed rx slot is refilled at once, so the
+        ring's pending count does not change as batches complete,
+        though its frames are new. Three batches of small frames hold
+        the pump's whole share of a full ring; when the first
+        completes, each slot it frees is refilled inside the release.
+        The new frames must be dispatched beside the two batches still
+        in flight, not only once fewer than two are."""
+        import threading
+
+        dp, a, _b = make_forwarding_dp()
+        rings = IORingPair(n_slots=64)  # hold cap 60
+        pump = DataplanePump(
+            dp, rings, max_batch=2048, fetch_workers=8, max_inflight=64,
+            fetch_delay=lambda seq: 1.0 if seq == 0 else 4.0)
+        pump.warm()
+        codec = PacketCodec()
+        ready = []  # 84 frames of 32 packets: 64 would fill a batch
+        for k in range(84):
+            scratch = np.zeros((VEC, rings.rx.snap), np.uint8)
+            frames = [make_frame(CLIENT_IP, SERVER_IP, proto=17,
+                                 sport=20000 + k, dport=1000 + j)
+                      for j in range(32)]
+            cols, m = codec.parse(frames, a, scratch)
+            ready.append(({c: v.copy() for c, v in cols.items()}, m,
+                          scratch))
+        sent, refill = [0], threading.Event()
+
+        def push(n):
+            for cols, m, scratch in ready[sent[0]:sent[0] + n]:
+                assert rings.rx.push(cols, m, payload=scratch)
+            sent[0] += n
+
+        def push_whole(n):  # no take sees a part of the n frames
+            with pump._held_lock:
+                push(n)
+
+        release = rings.rx.release
+
+        def release_and_refill():  # on the pump's writer thread
+            release()
+            if refill.is_set():
+                push(1)
+
+        rings.rx.release = release_and_refill
+
+        def wait_for(cond, timeout=5):
+            deadline = time.monotonic() + timeout
+            while not cond() and time.monotonic() < deadline:
+                time.sleep(0.001)
+            return cond()
+
+        push(20)
+        pump.start()
+        try:
+            for held in (20, 40, 60):  # batches of 20, 20 and 20
+                assert wait_for(lambda: len(pump._taken) == held)
+                if held < 60:
+                    push_whole(20 if held == 20 else 24)
+            # the ring is full, the hold cap spent
+            assert wait_for(lambda: pump.stats["batches"] == 3)
+            refill.set()  # the first batch's 20 slots, refilled
+            got = drain(rings, 20)
+            assert wait_for(lambda: sent[0] == 84)
+            refill.clear()
+            assert wait_for(lambda: pump.stats["batches"] == 4, 2)
+            assert pump.stats["inflight"] == 3
+            got += drain(rings, 64)
+            assert [int(s[0]) for s, _d, _i, _n in got] == \
+                [20000 + k for k in range(84)]
+            assert pump.stats["batch_errors"] == 0
+        finally:
+            assert pump.stop()
+            rings.close()
+
+
 class TestDispatchShutdown:
     def test_stop_under_load_never_hangs(self):
         """stop() while batches are dispatched and the (single) fetch

@@ -15,7 +15,9 @@ file. The persistent compilation cache stays off around the compiles.
 
 from __future__ import annotations
 
+import math
 import os
+import re
 from types import SimpleNamespace
 
 import pytest
@@ -46,9 +48,9 @@ def one_chip(topo):
 
 
 @pytest.fixture
-def compile_for(one_chip):
-    """``compile_for(fn, *shapes)`` → the compiled program's HLO text,
-    with the persistent cache off for the duration."""
+def compiled_for(one_chip):
+    """``compiled_for(fn, *shapes)`` → the compiled program, with the
+    persistent cache off for the duration."""
     from jax.experimental.compilation_cache import compilation_cache
 
     was = jax.config.jax_enable_compilation_cache
@@ -59,11 +61,17 @@ def compile_for(one_chip):
         args = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
                                            sharding=one_chip), shapes)
-        return jax.jit(fn).lower(*args).compile().as_text()
+        return jax.jit(fn).lower(*args).compile()
 
     yield run
     jax.config.update("jax_enable_compilation_cache", was)
     compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def compile_for(compiled_for):
+    """``compile_for(fn, *shapes)`` → the compiled program's HLO text."""
+    return lambda fn, *shapes: compiled_for(fn, *shapes).as_text()
 
 
 def S(shape, dtype):
@@ -193,18 +201,13 @@ def test_acl_local_bv_first_set_compiles(compile_for):
     assert "%acl_local_bv_first_set" in txt
 
 
-def test_local_only_step_serves_the_local_kernel(compile_for, monkeypatch):
-    """A node whose only policy sits in a local table: auto selects the
-    pallas rung from the local table's size, and the step's local
-    classify runs the local kernel (no global one: the table is empty)."""
-    import vpp_tpu.ops._pallas as pallas_mod
+def local_table_node():
+    """A node whose only policy sits in one 201-rule local table."""
     from vpp_tpu.ir.rule import Action, ContivRule, Protocol
     from vpp_tpu.pipeline.dataplane import Dataplane
-    from vpp_tpu.pipeline.graph import make_pipeline_step
     from vpp_tpu.pipeline.tables import DataplaneConfig
-    from vpp_tpu.pipeline.vector import Disposition, make_packet_vector
+    from vpp_tpu.pipeline.vector import Disposition
 
-    monkeypatch.setattr(pallas_mod, "use_pallas", lambda: True)
     dp = Dataplane(DataplaneConfig(max_tables=2, max_rules=256,
                                    classifier_bv_min_rules=128))
     pod = dp.add_pod_interface(("ns", "pod"))
@@ -216,6 +219,19 @@ def test_local_only_step_serves_the_local_kernel(compile_for, monkeypatch):
         + [ContivRule(action=Action.DENY, protocol=Protocol.TCP)])
     dp.assign_pod_table(("ns", "pod"), "T")
     dp.swap()
+    return dp, pod
+
+
+def test_local_only_step_serves_the_local_kernel(compile_for, monkeypatch):
+    """A node whose only policy sits in a local table: auto selects the
+    pallas rung from the local table's size, and the step's local
+    classify runs the local kernel (no global one: the table is empty)."""
+    import vpp_tpu.ops._pallas as pallas_mod
+    from vpp_tpu.pipeline.graph import make_pipeline_step
+    from vpp_tpu.pipeline.vector import make_packet_vector
+
+    monkeypatch.setattr(pallas_mod, "use_pallas", lambda: True)
+    dp, pod = local_table_node()
     assert dp.builder.glb_nrules == 0
     assert dp.kernel_snapshot()["classifier"]["impl"] == "pallas"
     pkts = make_packet_vector([{"src": "10.9.0.2", "dst": "10.9.0.3",
@@ -227,3 +243,96 @@ def test_local_only_step_serves_the_local_kernel(compile_for, monkeypatch):
                               sess_impl=dp.session_impl)
     txt = compile_for(step, dp.tables, pkts, jnp.int32(1))
     assert "%acl_local_bv_first_set" in txt
+
+
+HLO_DTYPES = {"pred": "bool", "s8": "int8", "u8": "uint8", "s16": "int16",
+              "u16": "uint16", "bf16": "bfloat16", "f16": "float16",
+              "s32": "int32", "u32": "uint32", "f32": "float32"}
+
+
+def entry_of(txt: str) -> dict:
+    """The ENTRY computation of compiled HLO text: {name: definition}."""
+    defs = {}
+    for line in txt[txt.index("\nENTRY"):].splitlines()[1:]:
+        m = re.match(r"\s*(ROOT )?%(\S+) = (.*)", line)
+        if m:
+            defs["ROOT" if m.group(1) else m.group(2)] = m.group(3)
+    return defs
+
+
+def copied_params(defs: dict) -> set:
+    """Parameters an ENTRY ``copy`` reads, through the async copies
+    that stage them in and out of VMEM."""
+    out = set()
+    for d in defs.values():
+        m = re.search(r" copy\(%([^,)\s]+)", d)
+        while m:
+            src = defs.get(m.group(1), "")
+            if " parameter(" in src:
+                out.add(m.group(1))
+                break
+            m = re.search(r" (?:copy|copy-done|copy-start|bitcast)"
+                          r"\(%([^,)\s]+)", src)
+    return out
+
+
+def root_buffers(defs: dict) -> list:
+    """(dtype, dims, device bytes) of each ENTRY output, its device
+    bytes padded to the tiles of the layout the compiler chose."""
+    sig = defs["ROOT"].split(" tuple(")[0]
+    out = []
+    for dt, dims, order, tile in re.findall(
+            r"([a-z]+\d+)\[([\d,]*)\]\{([\d,]*):T\(([\d,]+)\)", sig):
+        dims = tuple(int(x) for x in dims.split(",") if x)
+        padded = list(dims) or [1]
+        minor_first = [int(x) for x in order.split(",") if x] or [0]
+        for t, axis in zip([int(x) for x in tile.split(",")][::-1],
+                           minor_first):
+            padded[axis] = -(-padded[axis] // t) * t
+        dtype = jnp.dtype(HLO_DTYPES[dt])
+        out.append((dtype, dims, dtype.itemsize * math.prod(padded)))
+    return out
+
+
+def test_packed_step_outputs_only_written_leaves(compiled_for, monkeypatch):
+    """The packed step of a local-table node on the pallas rung: the
+    compiled ENTRY copies no ``acl_bv_*`` plane into an output, and its
+    outputs are exactly the written table leaves, the [5, B] result
+    and the aux row — in bytes, those buffers at the compiler's layouts
+    plus the output tuple's index table. The all-leaf boundary, as a
+    control, copies every BV plane out."""
+    import vpp_tpu.ops._pallas as pallas_mod
+    from vpp_tpu.pipeline.dataplane import (
+        PACKED_AUX_ROWS,
+        _packed_call,
+        _packed_tables,
+        packed_input_zeros,
+    )
+    from vpp_tpu.pipeline.graph import make_pipeline_step
+
+    monkeypatch.setattr(pallas_mod, "use_pallas", lambda: True)
+    dp, _pod = local_table_node()
+    assert dp.kernel_snapshot()["classifier"]["impl"] == "pallas"
+    step = make_pipeline_step(dp.classifier_impl, dp._skip_local,
+                              fast=dp._use_fastpath,
+                              fib_impl=dp.fib_impl,
+                              sess_impl=dp.session_impl)
+    args = (dp.tables, jnp.asarray(packed_input_zeros(256)), jnp.int32(1))
+    bv = {f"tables_{f}" for f in dp.tables._fields if f.startswith("acl_bv")}
+
+    full = compiled_for(_packed_tables(step, with_aux=True), *args)
+    assert bv <= {p.rsplit(".", 1)[0]
+                  for p in copied_params(entry_of(full.as_text()))}
+
+    new = compiled_for(_packed_call(step, with_aux=True), *args)
+    defs = entry_of(new.as_text())
+    assert not bv & {p.rsplit(".", 1)[0] for p in copied_params(defs)}
+    written = jax.eval_shape(_packed_call(step, with_aux=True), *args)[0]
+    want = [(x.dtype, x.shape) for x in jax.tree.leaves(written)]
+    want += [(jnp.dtype("int32"), (5, 256)),
+             (jnp.dtype("int32"), (PACKED_AUX_ROWS,))]
+    bufs = root_buffers(defs)
+    assert [(dt, dims) for dt, dims, _b in bufs] == want
+    table = -(-4 * len(bufs) // 512) * 512
+    assert new.memory_analysis().output_size_in_bytes == \
+        sum(b for _dt, _d, b in bufs) + table

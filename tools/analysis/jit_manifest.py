@@ -108,7 +108,9 @@ TRACED_ROOTS = {
     # the packed/chained IO boundary wrappers: jax.jit(_packed_call(fn))
     # — each has an off-signature and a telemetry-signature variant
     # (ISSUE 11) sharing one _core/_loop body
-    ("vpp_tpu/pipeline/dataplane.py", "_packed_call._core"),
+    # (_packed_tables, filtered to the leaves the step writes)
+    ("vpp_tpu/pipeline/dataplane.py", "_packed_tables._core"),
+    ("vpp_tpu/pipeline/dataplane.py", "_packed_tables.run"),
     ("vpp_tpu/pipeline/dataplane.py", "_packed_call.run"),
     ("vpp_tpu/pipeline/dataplane.py", "_chained_call.run_off"),
     ("vpp_tpu/pipeline/dataplane.py", "_chained_call.run_tel"),

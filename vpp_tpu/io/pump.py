@@ -10,8 +10,11 @@ result fetch: ``io_daemon_t_fetch_s=5.65`` vs ``t_dispatch=0.237``):
     jit cache stays small, and dispatches the packed single-transfer
     step WITHOUT waiting — JAX dispatch is asynchronous, and batches
     chain through the session tables device-side. Up to
-    ``max_inflight`` dispatched batches ride concurrently before the
-    stage backpressures;
+    ``max_inflight`` queued batches, one per fetch worker and one at
+    the writer, ride concurrently before the stage backpressures;
+    while two or more are in flight it takes only full batches where
+    the rx ring can hold them, so a device-bound node's batches stay
+    full;
   * the **adaptive chainer** engages when depth alone can't hide the
     round trip: backlog beyond one full ``max_batch`` bucket folds
     into a ``process_packed_chain`` K-stack — K packed batches in ONE
@@ -844,13 +847,20 @@ class DataplanePump:
                 "names": names}
 
     def _take_groups(self, rx, hold_cap: int, chain_cap: int,
-                     max_pkts: Optional[int] = None) -> list:
+                     max_pkts: Optional[int] = None,
+                     full_only: bool = False) -> list:
         """Peek pending BULK frames (in ring order, skipping rids the
         express lane took) into coalesce groups by PACKET count: a
         group closes when the next frame would overflow ``max_pkts``
         packets (default ``max_batch``; persistent mode compacts at
         the VEC descriptor-slot width). One group = one packed batch;
-        2+ groups = the chainer has a K-stack to fold. With a
+        2+ groups = the chainer has a K-stack to fold. ``full_only``
+        takes closed groups alone: a last group that no pending frame
+        overflowed (it could still grow) stays in the ring — where a
+        full group at its mean frame size needs at most a third of
+        ``hold_cap`` frames, so that it can fill beside two full
+        groups in flight. Smaller frames could never fill one while
+        two are in flight, and their last group is taken as it is. With a
         priority filter attached, only frames below the
         classification frontier are takeable (scan runs first each
         loop). Holds _held_lock across the whole peek block (a
@@ -882,7 +892,9 @@ class DataplanePump:
                 cur_n += f.n
                 budget -= 1
                 rid += 1
-            if cur and len(groups) < chain_cap:
+            if cur and len(groups) < chain_cap and (
+                    not full_only or cur_n >= max_pkts
+                    or 3 * len(cur) * max_pkts > cur_n * hold_cap):
                 groups.append(cur)
             if len(groups) > 1:
                 # trim to the largest chain rung ≤ the fold (a power
@@ -1068,6 +1080,11 @@ class DataplanePump:
         # never hold every slot: the producer needs headroom to keep
         # writing while K batches are in flight
         hold_cap = max(2, rx.ring.n_slots - 4)
+        # batches in flight at most: the queue, one per fetch worker,
+        # one at the writer
+        window = self.max_inflight + self.workers + 1
+        # (ring base, pending) when a full-only take found none
+        short_at = None
         while not self._stop.is_set():
             self._governor_tick()
             tracer = self.dp.tracer
@@ -1096,10 +1113,14 @@ class DataplanePump:
             if eg is not None:
                 self._dispatch_or_fail([eg], slow, pri=True)
                 continue
-            if self._inflight.full():
+            if self._inflight.full() or self.stats["inflight"] >= window:
                 # don't take a bulk group whose hand-off would BLOCK
                 # this thread — a blocked put can't scan for express
-                # arrivals, and the lane's bound is the scan cadence
+                # arrivals, and the lane's bound is the scan cadence.
+                # Nor one past the window: fetches that complete out
+                # of order park in the writer's reorder buffer, and
+                # each parked batch would free a queue slot for one
+                # more dispatch (and its frames held in the ring)
                 time.sleep(self.poll_s)
                 continue
             if self.tenants is not None:
@@ -1121,12 +1142,29 @@ class DataplanePump:
                     continue
                 self._dispatch_or_fail(taken[1], slow)
                 continue
-            with Timed("pump.take"):
-                groups = self._take_groups(rx, hold_cap, chain_cap,
-                                           max_pkts)
-            if not groups:
+            # With a batch on the device and another queued behind it,
+            # a short batch only adds one more step's fixed cost: take
+            # full batches alone and leave a short one to grow in the
+            # ring until fewer than two are in flight. (Frames held in
+            # flight count against the ring, so a deep window of short
+            # batches would keep them short.) Frames too small to fill
+            # a batch in the ring keep the whole window
+            # (_take_groups). A take that came back empty is retried
+            # only once a frame has arrived or been released.
+            full_only = self.stats["inflight"] >= 2
+            with self._held_lock:
+                ring_at = (self._consumed_base, rx.pending())
+            if full_only and ring_at == short_at:
                 time.sleep(self.poll_s)
                 continue
+            with Timed("pump.take"):
+                groups = self._take_groups(rx, hold_cap, chain_cap,
+                                           max_pkts, full_only)
+            if not groups:
+                short_at = ring_at if full_only else None
+                time.sleep(self.poll_s)
+                continue
+            short_at = None
             if gov is not None:
                 if not gov.admit(False, self._backlog()):
                     # shedding forces chain_cap=1, so refusal covers

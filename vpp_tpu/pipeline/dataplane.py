@@ -42,6 +42,17 @@ def _packed_call(step, with_aux: bool = False, tel: str = "off"):
     """Wrap a pipeline step with a bit-packed IO boundary: ONE [5, B]
     int32 input and ONE [5, B] int32 output.
 
+    Returns ``(written, out[, aux])``. ``written`` is a dict holding
+    only the table leaves the traced step writes (``_written_leaves``):
+    for the default config the reflective and NAT session columns,
+    their two sweep cursors and ``fib_ecmp_c`` — 20 of 174. Every other
+    leaf stays with the tables object the caller passed in, which the
+    caller owns (nothing of it is donated): the program has no output
+    for it, so XLA neither copies it nor allocates a buffer for it,
+    and ``tables._replace(**written)`` is the step's new tables.
+    ``_packed_tables`` is the same boundary returning the whole tables
+    pytree, for the ring window program, whose carry is donated whole.
+
     ``with_aux=True`` additionally returns a ``[PACKED_AUX_ROWS]``
     int32 summary whose rows are named by ``PACKED_AUX_SCHEMA`` (the
     ONE schema constant every dispatch form — packed, chained, ring —
@@ -90,6 +101,31 @@ def _packed_call(step, with_aux: bool = False, tel: str = "off"):
     addresses/ports, never protocol or length), so the tx side reuses
     the rx ring columns for them — they don't travel back.
     """
+    full = _packed_tables(step, with_aux=with_aux, tel=tel)
+
+    def run(tables, *args):
+        new_tables, *rest = full(tables, *args)
+        return (_written_leaves(tables, new_tables), *rest)
+
+    return run
+
+
+def _written_leaves(tables, new_tables) -> dict:
+    """The leaves of ``new_tables`` the traced step wrote, by field
+    name: every output leaf that is not its own input. A leaf the step
+    only reads comes back as the very tracer it went in as — the stages
+    rebuild the tables with ``_replace`` and ``lax.cond`` forwards a
+    pass-through operand — so its output var IS its input var in the
+    traced program. Whatever cannot be told apart that way counts as
+    written, which is the safe side: it is returned and committed."""
+    return {f: b for f, a, b in zip(tables._fields, tables, new_tables)
+            if b is not a}
+
+
+def _packed_tables(step, with_aux: bool = False, tel: str = "off"):
+    """``_packed_call``'s boundary returning the whole tables pytree
+    (``(tables', out[, aux])``): what ``_packed_call`` filters, and the
+    per-slot body of the ring window program."""
 
     def _core(tables, flat, now, rx_stamp, now_us):
         from jax import lax
@@ -176,38 +212,39 @@ def _chained_call(step, with_aux: bool = False, tel: str = "off"):
     the 'K-chained device steps synced once' lever of docs/LATENCY.md
     (VERDICT r3 Next #4). Latency of the FIRST frame rises to the
     chain's span, so this serves throughput-with-bounded-sync, not
-    single-frame latency. ``with_aux`` stacks the per-step
-    [PACKED_AUX_ROWS] aux summaries into a [K, PACKED_AUX_ROWS] array
-    next to the [K, 5, B] results. With ``tel`` on, the scan
-    additionally carries per-sub-batch rx stamps ([K] int32 µs) and
-    the dispatch clock, feeding the device latency histogram exactly
-    like K separate packed dispatches would."""
-    packed = _packed_call(step, with_aux=with_aux, tel=tel)
+    single-frame latency. Returns ``(written, outs[, auxs])``: the
+    table leaves one packed step writes (``_packed_call``) after the
+    K-th step, the [K, 5, B] results and, with ``with_aux``, the
+    stacked [K, PACKED_AUX_ROWS] aux summaries. Only the written
+    leaves ride the scan carry; the rest are closed over, read-only.
+    With ``tel`` on, the scan additionally carries per-sub-batch rx
+    stamps ([K] int32 µs) and the dispatch clock, feeding the device
+    latency histogram exactly like K separate packed dispatches
+    would."""
+    full = _packed_tables(step, with_aux=with_aux, tel=tel)
+
+    def _scan(tables, xs, sub):
+        from jax import lax
+
+        # the written set of one sub-step, read off a trace of it
+        x0 = jax.tree_util.tree_map(lambda a: a[0], xs)
+        names = tuple(jax.eval_shape(
+            lambda t, x: _written_leaves(t, sub(t, x)[0]), tables, x0))
+
+        def body(carry, x):
+            new_tables, *rest = sub(tables._replace(**carry), x)
+            return {n: getattr(new_tables, n) for n in names}, tuple(rest)
+
+        carry, ys = lax.scan(
+            body, {n: getattr(tables, n) for n in names}, xs)
+        return (carry, *ys)
 
     def run_off(tables, flats, now):
-        from jax import lax
-
-        def body(tbl, flat):
-            if with_aux:
-                tbl2, out, aux = packed(tbl, flat, now)
-                return tbl2, (out, aux)
-            tbl2, out = packed(tbl, flat, now)
-            return tbl2, out
-
-        return lax.scan(body, tables, flats)
+        return _scan(tables, flats, lambda tbl, flat: full(tbl, flat, now))
 
     def run_tel(tables, flats, now, rx_stamps, now_us):
-        from jax import lax
-
-        def body(tbl, xs):
-            flat, stamp = xs
-            if with_aux:
-                tbl2, out, aux = packed(tbl, flat, now, stamp, now_us)
-                return tbl2, (out, aux)
-            tbl2, out = packed(tbl, flat, now, stamp, now_us)
-            return tbl2, out
-
-        return lax.scan(body, tables, (flats, rx_stamps))
+        return _scan(tables, (flats, rx_stamps),
+                     lambda tbl, xs: full(tbl, xs[0], now, xs[1], now_us))
 
     return run_off if tel == "off" else run_tel
 
@@ -284,7 +321,7 @@ def _ring_call(step, slots: int, tel: str = "off"):
       (tables', cursor + consumed, tx_ring, aux_ring,
        tel [tel_rider_width])
     """
-    packed = _packed_call(step, with_aux=True, tel=tel)
+    packed = _packed_tables(step, with_aux=True, tel=tel)
 
     def _loop(tables, cursor, rx_ring, rx_now, rx_stamp, now_us,
               rx_tail):
@@ -833,6 +870,11 @@ class Dataplane:
         # counted on the host from the packed rx_if row; folded into
         # the pump's stats the same way
         self.host_counters = {"local_table_pkts": 0}
+        # (leaves, bytes) of the tables the last packed or chained step
+        # variant returned per call — the leaves it writes
+        # (_packed_call), not the 174 it reads; sizes, not counts
+        self.step_out = (0, 0)
+        self._out_step = None  # the step variant step_out describes
         # Session time base: wall-clock ticks (TICKS_PER_SEC), not frame
         # counts — aging semantics must not depend on offered load
         # (VERDICT r1 Weak #5; the reference ages on timers).
@@ -1212,6 +1254,10 @@ class Dataplane:
                                session_pallas_fits(self.config),
                                "table exceeds VMEM budget"),
                 },
+                # table leaves/bytes the packed step returns per call
+                # (0 before the first packed or chained dispatch)
+                "step_out": {"leaves": self.step_out[0],
+                             "bytes": self.step_out[1]},
             }
 
     def fib_snapshot(self) -> Optional[dict]:
@@ -1483,13 +1529,21 @@ class Dataplane:
         batch, 20 bytes per packet each way.
 
         ``with_aux=True`` returns ``(out, aux)`` instead, where ``aux``
-        is the DEVICE [8] int32 summary
-        ``[fastpath, rx, sess_hits, insert_fails, evictions,
-        ml_scored, ml_flagged, ml_drops]`` from the
-        same program. It is
+        is the DEVICE [PACKED_AUX_ROWS] int32 summary
+        (``PACKED_AUX_SCHEMA``) from the same program. It is
         measured on BOTH tiers (fastpath is 0 on the full chain), so
         the session-hit regime signal exists even with the fast path
         disengaged.
+
+        Only the table leaves the step writes cross back from the
+        device (``_packed_call``: the session columns, sweep cursors
+        and ECMP counters). The commit rebuilds the live tables from
+        the tables object the step was called with and those leaves;
+        every read-only plane stays the buffer that object holds, owned
+        by whoever published it (``swap``, ``adopt_sessions``) and
+        shared with every reader of ``dp.tables`` — none of it is
+        donated. ``step_out`` says how many leaves and bytes come back
+        per call.
 
         ``commit=False`` discards the resulting session-table state (a
         probe-like classify): REQUIRED for any caller other than the
@@ -1528,11 +1582,13 @@ class Dataplane:
             if tel:
                 args += (jnp.int32(stamp_us), jnp.int32(now_us))
         with Timed("dp.step_call", timers, "t_dp_call"):
-            new_tables, out, aux = step(tables, *args)
+            written, out, aux = step(tables, *args)
+        self._note_step_out(step, written)
         if if_local is not None:
             self.host_counters["local_table_pkts"] += local_table_pkts(
                 flat, if_local)
         if commit:
+            new_tables = tables._replace(**written)
             with self._lock:
                 if tables is self.tables:
                     self.tables = new_tables
@@ -1581,14 +1637,25 @@ class Dataplane:
                 args += (jnp.asarray(stamps_us, jnp.int32),
                          jnp.int32(now_us))
         with Timed("dp.step_call", timers, "t_dp_call"):
-            new_tables, (outs, auxs) = step(tables, *args)
+            written, outs, auxs = step(tables, *args)
+        self._note_step_out(step, written)
         if if_local is not None:
             self.host_counters["local_table_pkts"] += local_table_pkts(
                 flats, if_local)
+        new_tables = tables._replace(**written)
         with self._lock:
             if tables is self.tables:
                 self.tables = new_tables
         return (outs, auxs) if with_aux else outs
+
+    def _note_step_out(self, step, written: dict) -> None:
+        """Record the table leaves and bytes ``step`` returns per call
+        (``step_out``). A variant's written set is fixed once traced,
+        so it is summed once per variant switch."""
+        if step is not self._out_step:
+            self._out_step = step
+            self.step_out = (len(written), sum(
+                int(v.nbytes) for v in written.values()))
 
     # --- device telemetry (ops/telemetry.py; ISSUE 11) ---
     def telemetry_snapshot(self) -> Optional[dict]:
