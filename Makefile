@@ -3,7 +3,7 @@
 
 PY ?= python
 
-.PHONY: test test-race chaos lint verify bench autotune autotune-check all
+.PHONY: test test-race chaos lint verify all
 
 all: lint test
 
@@ -36,22 +36,7 @@ chaos:
 # --transfers host-fetch allowlisting, --donate use-after-donate. The
 # registry passes (--metrics/--counters/--tables) import jax, so
 # tier-1 runs them from tests instead (test_exposition / test_acl_bv).
-# autotune-check rides along: a drifted tuned/cpu.json is a lint-class
-# failure (the committed profile must round-trip the config loader).
-lint: autotune-check
+lint:
 	$(PY) tools/lint.py --jax --threads --partitions --uploads \
 		--transfers --donate
 
-# Driver-facing headline benchmark (real TPU; one JSON line).
-bench:
-	$(PY) bench.py
-
-# Config-knob autotuner (ISSUE 16; tools/autotune.py): sweep the
-# backend-dependent geometry knobs and write tuned/<backend>.json.
-autotune:
-	$(PY) tools/autotune.py
-
-# Validate the committed CPU profile round-trips through the SAME
-# config loader the agent boots with (knobs land, floor clamps).
-autotune-check:
-	$(PY) tools/autotune.py --check tuned/cpu.json

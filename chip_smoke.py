@@ -245,12 +245,39 @@ def node_subnet(i: int) -> str:
     return f"10.{1 + (i >> 8)}.{i & 255}.0/24"
 
 
+def build_rules(n_rules: int):
+    """Policy rule set shaped like tests/policy/perf/gen-policy.py:
+    CIDR-block x port permits with interleaved deny excepts, then a
+    terminal deny-all (the renderer-cache table form)."""
+    from vpp_tpu.ir.rule import Action, ContivRule, Protocol
+
+    rules = []
+    i = 0
+    while len(rules) < n_rules - 1:
+        block = i % 1000
+        port = 8000 + (i // 1000) % 20
+        net = ipaddress.ip_network(
+            f"172.{16 + block // 256}.{block % 256}.0/24"
+        )
+        action = Action.DENY if i % 6 == 5 else Action.PERMIT
+        rules.append(
+            ContivRule(
+                action=action,
+                src_network=net,
+                protocol=Protocol.TCP,
+                dest_port=port,
+            )
+        )
+        i += 1
+    rules.append(ContivRule(action=Action.DENY))
+    return rules
+
+
 def build_node(knobs: dict, n_rules: int = N_RULES,
                n_nodes: int = N_NODES, sess_slots: int = SESS_SLOTS):
     """One node of a ``n_nodes`` cluster: local /32 pods, a /24 per
     peer node toward the uplink, a default route, a gen-policy-shaped
     global table and one VIP with ``N_BACKENDS`` weighted backends."""
-    from bench import build_rules
     from vpp_tpu.ir.rule import Action, ContivRule, Protocol
     from vpp_tpu.pipeline.dataplane import Dataplane
     from vpp_tpu.pipeline.tables import DataplaneConfig

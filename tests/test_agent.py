@@ -311,6 +311,19 @@ def test_config_yaml_roundtrip(tmp_path):
         load_config(str(bad))
 
 
+@pytest.mark.parametrize("raw,match", [
+    ({"tuned_profile": "tuned/cpu.json"},
+     r"unknown config keys: \['tuned_profile'\]"),
+    ({"io": {"no_such_io_knob": 1}},
+     r"unknown config keys in 'io': \['no_such_io_knob'\]"),
+])
+def test_from_dict_refuses_unknown_keys(raw, match):
+    """A key no config section declares is refused at load, at top
+    level and inside a section, never silently ignored."""
+    with pytest.raises(ValueError, match=match):
+        AgentConfig.from_dict(raw)
+
+
 def test_maintenance_tick_ages_sessions_and_publishes():
     store, ksr, agent = boot()
     ip1 = add_pod(agent, "c1", "p1")

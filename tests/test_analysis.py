@@ -720,6 +720,22 @@ def test_donate_lint_clean_tree():
     assert [str(f) for f in donate_lint(REPO)] == []
 
 
+@pytest.mark.parametrize("module,name", [
+    ("lint", "ROOTS"),
+    ("analysis.uploadlint", "UPLOAD_ROOTS"),
+    ("analysis.transferlint", "TRANSFER_ROOTS"),
+    ("analysis.donatelint", "DONATE_ROOTS"),
+])
+def test_lint_roots_exist(module, name):
+    """Every path a lint root tuple names exists: a missing root is
+    scanned as an empty tree, so a deleted file would silently drop
+    out of the lint instead of failing it."""
+    import importlib
+
+    roots = getattr(importlib.import_module(module), name)
+    assert [r for r in roots if not (REPO / r).exists()] == []
+
+
 # --- --uploads: mark dataflow ----------------------------------------
 
 def test_upload_mini_fixture_clean(tmp_path):

@@ -172,7 +172,7 @@ class TestDifferential:
         assert int(ref.stats.tx) == n_valid
         assert int(ref.stats.nat_reversed) == 1
         assert int(got.stats.sess_hits) == n_valid
-        # the standalone fast kernel agrees too (bench uses it)
+        # the standalone fast kernel agrees too
         raw = step_fast(r1.tables, rep, jnp.int32(6))
         assert np.array_equal(np.asarray(raw.disp), np.asarray(ref.disp))
         assert int(raw.stats.fastpath) == 1

@@ -13,14 +13,11 @@ and, where one exists, an independent NumPy oracle:
 - the CPU dispatch identities (the pallas-rung entry points ARE the
   jnp rungs off-TPU), the three selection ladders' pallas_ok bit, the
   config-time mesh rejection, the step-factory bit-exactness of a
-  fully pallas-knobbed step, tuned-profile loading (tools/autotune.py
-  consumer side), the VMEM fit gate and the PALLAS_KERNELS manifest
-  lint (tools/analysis/registries.py, run from tier-1 here like the
-  other registry passes).
+  fully pallas-knobbed step, the VMEM fit gate and the PALLAS_KERNELS
+  manifest lint (tools/analysis/registries.py, run from tier-1 here
+  like the other registry passes).
 """
 
-import json
-import os
 import sys
 from pathlib import Path
 from types import SimpleNamespace
@@ -399,90 +396,6 @@ def test_pallas_step_bitexact_vs_reference_step():
     for a, c in zip(jax.tree_util.tree_leaves(r_pal),
                     jax.tree_util.tree_leaves(r_ref)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(c))
-
-
-# --- tuned profiles (tools/autotune.py consumer side) -----------------
-
-
-def _write_profile(tmp_path, **kw):
-    prof = dict(backend="cpu", floor_us=50.0,
-                knobs={"dataplane": {"sess_ways": 8},
-                       "io": {"io_ring_slots": 16},
-                       "env": {"VPPT_TEST_TUNED_KNOB": "4096"}})
-    prof.update(kw)
-    p = tmp_path / "prof.json"
-    p.write_text(json.dumps(prof))
-    return str(p)
-
-
-@pytest.fixture
-def _clean_env():
-    saved = os.environ.pop("VPPT_TEST_TUNED_KNOB", None)
-    yield
-    if saved is None:
-        os.environ.pop("VPPT_TEST_TUNED_KNOB", None)
-    else:
-        os.environ["VPPT_TEST_TUNED_KNOB"] = saved
-
-
-def test_tuned_profile_knobs_are_defaults(tmp_path, _clean_env):
-    """Profile knobs land as per-key DEFAULTS: explicit config wins,
-    env knobs apply via setdefault, the floor clamps a sub-floor SLO
-    up (and leaves 0 = disabled alone)."""
-    from vpp_tpu.cmd.config import AgentConfig
-
-    path = _write_profile(tmp_path)
-    cfg = AgentConfig.from_dict({"tuned_profile": path})
-    assert cfg.dataplane.sess_ways == 8
-    assert cfg.io.io_ring_slots == 16
-    assert os.environ["VPPT_TEST_TUNED_KNOB"] == "4096"
-
-    cfg = AgentConfig.from_dict({
-        "tuned_profile": path,
-        "dataplane": {"sess_ways": 2},
-        "io": {"io_ring_slots": 8, "latency_slo_us": 1},
-    })
-    assert cfg.dataplane.sess_ways == 2   # explicit config wins
-    assert cfg.io.io_ring_slots == 8
-    assert cfg.io.latency_slo_us == 50    # clamped up to the floor
-
-    cfg = AgentConfig.from_dict({
-        "tuned_profile": path,
-        "io": {"latency_slo_us": 900},
-    })
-    assert cfg.io.latency_slo_us == 900   # above floor: untouched
-    cfg = AgentConfig.from_dict({"tuned_profile": path})
-    assert cfg.io.latency_slo_us == 0     # 0 = disabled stays disabled
-
-    # exported environment beats the profile's env defaults
-    os.environ["VPPT_TEST_TUNED_KNOB"] = "111"
-    AgentConfig.from_dict({"tuned_profile": path})
-    assert os.environ["VPPT_TEST_TUNED_KNOB"] == "111"
-
-
-def test_tuned_profile_refuses_malformed(tmp_path):
-    from vpp_tpu.cmd.config import load_tuned_profile
-
-    with pytest.raises(ValueError, match="section"):
-        load_tuned_profile(_write_profile(
-            tmp_path, knobs={"bogus": {"x": 1}}))
-    with pytest.raises(ValueError, match="VPPT_"):
-        load_tuned_profile(_write_profile(
-            tmp_path, knobs={"env": {"PATH": "/tmp"}}))
-    with pytest.raises(ValueError):
-        load_tuned_profile(str(tmp_path / "missing.json"))
-    assert load_tuned_profile("") is None
-
-
-def test_autotune_check_accepts_good_profile(tmp_path, _clean_env):
-    if str(TOOLS) not in sys.path:
-        sys.path.insert(0, str(TOOLS))
-    import autotune
-
-    assert autotune.check_profile(_write_profile(tmp_path)) == []
-    problems = autotune.check_profile(
-        _write_profile(tmp_path, floor_us="fast"))
-    assert problems
 
 
 # --- the PALLAS_KERNELS manifest lint (registry pass, run tier-1) -----

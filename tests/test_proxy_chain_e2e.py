@@ -143,8 +143,7 @@ else:
 @pytest.fixture(scope="module")
 def mesh(tmp_path_factory):
     """Admission server + 10k-rule engine (shared scenario builders,
-    vpp_tpu/hoststack/scenarios.py — the same rule shapes
-    bench.proxy_chain_bench measures) + backend and proxy subprocesses
+    vpp_tpu/hoststack/scenarios.py) + backend and proxy subprocesses
     under the fail-closed shim."""
     loop = ipi("127.0.0.1")
     engine = SessionRuleEngine(capacity=16384)

@@ -813,8 +813,8 @@ class TestValidation:
                 "node_name": "n1",
                 "io": {"io_tenant_quantum": -1},
             })
-        # the pump caps a WFQ take at the quantum (the isolation
-        # bench's latency/throughput dial)
+        # the pump caps a WFQ take at the quantum (the tenant
+        # latency/throughput dial)
         dp, a, _b = _pump_dp()
         cls = TenantClassifier(tenant_entries_from_config(
             [{"id": 1, "prefixes": [T1_NET]}]))

@@ -37,7 +37,7 @@ from typing import Dict, List, Optional, Set, Tuple
 
 from analysis.common import Finding, iter_source_files, parse_suppressions
 
-DONATE_ROOTS = ("vpp_tpu", "bench.py", "tests")
+DONATE_ROOTS = ("vpp_tpu", "tests")
 
 
 def _callee_repr(func) -> Optional[str]:

@@ -33,9 +33,6 @@ JIT_SITES = {
     ("vpp_tpu/pipeline/dataplane.py", "Dataplane.encap_remote"):
         "lazy vxlan_encap jit; module-level target fn, built once per "
         "dataplane on first remote-disposed frame",
-    ("vpp_tpu/pipeline/dataplane.py", "Dataplane.time_classifier"):
-        "diagnostic classify probe; per-impl cache on the instance, "
-        "bench/operator path — never hot",
     ("vpp_tpu/pipeline/graph.py", "<module>"):
         "pipeline_step_jit: the module-level reference step (tests)",
     ("vpp_tpu/pipeline/tables.py", "_glb_update_fn"):
@@ -99,12 +96,10 @@ TRACED_ROOTS = {
     # set-associative session table (ISSUE 6): the insert core and the
     # amortized in-step sweep are traced INTO every step variant via
     # graph.py; session_expire's impl is wrapped by the module-level
-    # jit registered above; the linear-probe baseline is traced only by
-    # bench.py's jitted old-vs-new shoot-out
+    # jit registered above
     ("vpp_tpu/ops/session.py", "hashmap_insert"),
     ("vpp_tpu/ops/session.py", "session_sweep"),
     ("vpp_tpu/ops/session.py", "_session_expire_impl"),
-    ("vpp_tpu/ops/session.py", "hashmap_insert_linear"),
     # the packed/chained IO boundary wrappers: jax.jit(_packed_call(fn))
     # — each has an off-signature and a telemetry-signature variant
     # (ISSUE 11) sharing one _core/_loop body
@@ -150,8 +145,8 @@ TRACED_ROOTS = {
     ("vpp_tpu/ops/fib.py", "resolve_fib_slot"),
     ("vpp_tpu/ops/fib.py", "fib_flow_mix"),
     ("vpp_tpu/ops/fib.py", "ip4_lookup"),
-    # classifier implementations reach jit through _classifier_fns /
-    # time_classifier's subscripted call — enumerate them explicitly
+    # classifier implementations reach jit through _classifier_fns'
+    # subscripted call — enumerate them explicitly
     ("vpp_tpu/ops/acl.py", "acl_classify_global"),
     ("vpp_tpu/ops/acl.py", "acl_classify_local"),
     ("vpp_tpu/ops/acl.py", "acl_local_none"),
@@ -255,17 +250,6 @@ DONATED_JIT_SITES = {
         "the flat input column block (a fresh jnp.asarray temp at every "
         "call site); ring donates the tables carry + cursor + rx window, "
         "owned by the persistent pump which threads the returned carry"),
-    ("bench.py", "sub_benches"): (
-        "throughput loop donates its private dataplane's tables; the "
-        "carry is rebound from StepResult every iteration"),
-    ("bench.py", "session_scale_bench"): (
-        "hashmap shoot-out donates the pristine() column sets (rebuilt "
-        "per window) and the 10M-resident insert carry (rebound from "
-        "the result tuple)"),
-    ("bench.py", "_run"): (
-        "headline loop donates its private dataplane's tables; carry "
-        "rebound from StepResult; commit_bench runs on its OWN "
-        "dataplane for exactly this reason (its docstring)"),
 }
 
 # Donating CALL sites the use-after-donate dataflow checks:
@@ -294,20 +278,4 @@ DONATING_CALLS = {
         (1,),
         "chained packed block: same fresh-temp discipline as "
         "process_packed"),
-    ("bench.py", "measure_mpps", "step"): (
-        (0,),
-        "tables carry donated and rebound from res.tables each "
-        "iteration"),
-    ("bench.py", "session_scale_bench", "fn"): (
-        (0, 1, 2, 3, 4, 5),
-        "the six hashmap columns are rebuilt by pristine() before "
-        "every donating call"),
-    ("bench.py", "session_scale_bench", "insert"): (
-        (0,),
-        "10M-resident carry: rebound from the result tuple in the "
-        "same statement"),
-    ("bench.py", "_run", "step"): (
-        (0,),
-        "headline tables carry: rebound from res.tables each "
-        "iteration"),
 }

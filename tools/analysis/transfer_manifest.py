@@ -2,7 +2,7 @@
 
 `TRANSFER_SITES` maps ``(repo-relative path, function qualname)`` to the
 reason the fetch is sanctioned.  A qualname of ``"*"`` approves a whole
-file (bench captures).  Everything else that materializes a
+file.  Everything else that materializes a
 DataplaneTables-reachable device value on host is a finding — the
 "aggregate on host" regression class (PRs 6/8/12).
 
@@ -18,9 +18,6 @@ TRANSFER_SITES: Dict[Tuple[str, str], str] = {
     ("vpp_tpu/pipeline/persistent.py", "PersistentPump._fetch_loop"): (
         "THE packed-result fetch: one device_get per ring window of "
         "tx/aux riders (+ telemetry rider), never table columns"),
-    ("bench.py", "*"): (
-        "bench captures: measurement harness, results must land on "
-        "host; sections run off the serving path by construction"),
     # --- snapshot drains (PR 8): the sanctioned bulk session fetches --
     ("vpp_tpu/pipeline/snapshot.py", "SessionSnapshotter._drain"): (
         "the periodic session checkpoint drain: amortized over the "

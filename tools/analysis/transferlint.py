@@ -8,8 +8,8 @@ transport".  The pass taints every value reachable from a
 ``tables`` or annotated ``DataplaneTables``, the persistent pump's
 table-carry slots) and flags host-materialization sinks on tainted
 values, unless the enclosing function is an approved fetch site in
-`transfer_manifest.TRANSFER_SITES` (snapshot drains, bench captures,
-the packed-result fetch).
+`transfer_manifest.TRANSFER_SITES` (snapshot drains, the packed-result
+fetch).
 
 Taint propagates through names, attribute/subscript access, tuple
 packing, arithmetic, and device-side calls (``jnp.*`` / ``jax.lax.*``);
@@ -37,7 +37,7 @@ from typing import Dict, List, Set, Tuple
 
 from analysis.common import Finding, iter_source_files, parse_suppressions
 
-TRANSFER_ROOTS = ("vpp_tpu", "bench.py")
+TRANSFER_ROOTS = ("vpp_tpu",)
 
 # attribute names that hold a DataplaneTables pytree
 TAINT_ATTRS = {"tables", "_tables0", "_tables_pending", "_tables_final"}

@@ -53,7 +53,7 @@ from analysis.common import Finding, iter_source_files, parse_suppressions
 
 TABLES_REL = "vpp_tpu/pipeline/tables.py"
 # roots scanned for external writes to staged builder attributes
-UPLOAD_ROOTS = ("vpp_tpu", "bench.py")
+UPLOAD_ROOTS = ("vpp_tpu",)
 
 LEDGER_NAMES = ("SESSION_FIELDS", "TELEMETRY_FIELDS",
                 "TENANCY_STATE_FIELDS", "FIB_STATE_FIELDS")

@@ -239,10 +239,6 @@ class DebugCLI:
             if not b.bv_ok():
                 detail += " (NOT eligible: non-prefix mask rule)"
             lines.append(detail)
-        ns = getattr(dp, "classify_ns_pkt", None)
-        if ns is not None:
-            lines.append(f"  classify probe: {ns:.1f} ns/pkt "
-                         f"(time_classifier diagnostic)")
         for table_id, slot in sorted(dp.table_slots.items()):
             n = int(dp.builder.acl_nrules[slot])
             lines.append(f"local table {table_id} (slot {slot}, {n} rules):")

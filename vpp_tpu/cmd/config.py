@@ -296,22 +296,10 @@ class AgentConfig:
     io: IOConfig = dataclasses.field(default_factory=IOConfig)
     # multi-chip mesh mode (ignored by the standalone vpp-tpu-agent)
     mesh: MeshConfig = dataclasses.field(default_factory=MeshConfig)
-    # autotuned knob profile (ISSUE 16; tools/autotune.py): path of a
-    # ``tuned/<backend>.json`` the sweep emitted. Loaded BEFORE section
-    # build as per-key DEFAULTS — any knob the YAML sets explicitly
-    # wins over the profile. The profile's measured ``floor_us`` is
-    # the governor's achievable-latency floor: a configured
-    # ``io.latency_slo_us`` below it is clamped UP at load (an SLO the
-    # hardware cannot meet would pin the governor at the 1-slot floor
-    # forever, shedding for nothing). "" disables.
-    tuned_profile: str = ""
 
     @classmethod
     def from_dict(cls, d: dict) -> "AgentConfig":
         d = dict(d or {})
-        profile = load_tuned_profile(d.get("tuned_profile") or "")
-        if profile is not None:
-            apply_tuned_profile(d, profile)
 
         def build_section(name: str, section_cls, fields) -> None:
             if name not in d:
@@ -368,16 +356,6 @@ class AgentConfig:
                 raise ValueError(
                     "io.io_tenant_quantum must be >= 0 (packets; "
                     "0 = a full slot/batch)")
-        if profile is not None and "io" in d:
-            # governor SLO floor (ISSUE 16): the tuned profile's
-            # measured floor_us is the best latency the swept knobs
-            # achieved on this backend — an SLO below it is
-            # unreachable, so clamp up rather than let the governor
-            # shed traffic chasing it
-            floor = float(profile.get("floor_us") or 0.0)
-            slo = int(getattr(d["io"], "latency_slo_us", 0))
-            if floor > 0 and 0 < slo < floor:
-                d["io"].latency_slo_us = int(-(-floor // 1))
         build_section(
             "mesh", MeshConfig,
             {f.name for f in dataclasses.fields(MeshConfig)},
@@ -387,80 +365,6 @@ class AgentConfig:
         if unknown:
             raise ValueError(f"unknown config keys: {sorted(unknown)}")
         return cls(**d)
-
-
-#: tuned-profile sections the autotuner may set knobs in — anything
-#: else in "knobs" is refused at load (a profile is config, so a typo
-#: fails HERE with a clear message, not as a silently ignored key).
-#: "env" carries VPPT_* process knobs (e.g. VPPT_LPM_HINT_MIN — the
-#: LPM stride-hint engage threshold has no YAML twin); applied via
-#: os.environ.setdefault so an explicitly exported variable wins.
-TUNED_PROFILE_SECTIONS = ("dataplane", "io", "env")
-
-
-def load_tuned_profile(path: str) -> Optional[dict]:
-    """Parse a ``tuned/<backend>.json`` autotuner profile (ISSUE 16).
-
-    Returns None when ``path`` is empty. Raises ValueError on a
-    malformed profile — shape problems are config errors, not
-    first-boot surprises. Knob VALUES are validated downstream by the
-    same section builders that validate YAML keys (from_dict), so a
-    profile can never smuggle in a knob the YAML could not set.
-    """
-    if not path:
-        return None
-    import json
-
-    try:
-        with open(path) as f:
-            profile = json.load(f)
-    except OSError as e:
-        raise ValueError(f"tuned_profile {path!r}: {e}") from e
-    except json.JSONDecodeError as e:
-        raise ValueError(f"tuned_profile {path!r}: bad JSON: {e}") from e
-    if not isinstance(profile, dict):
-        raise ValueError(f"tuned_profile {path!r}: not a JSON object")
-    knobs = profile.get("knobs", {})
-    if not isinstance(knobs, dict):
-        raise ValueError(f"tuned_profile {path!r}: 'knobs' not an object")
-    unknown = set(knobs) - set(TUNED_PROFILE_SECTIONS)
-    if unknown:
-        raise ValueError(
-            f"tuned_profile {path!r}: unknown knob sections "
-            f"{sorted(unknown)} (allowed: {list(TUNED_PROFILE_SECTIONS)})")
-    for section, vals in knobs.items():
-        if not isinstance(vals, dict):
-            raise ValueError(
-                f"tuned_profile {path!r}: knobs.{section} not an object")
-    bad_env = [k for k in knobs.get("env", {})
-               if not str(k).startswith("VPPT_")]
-    if bad_env:
-        raise ValueError(
-            f"tuned_profile {path!r}: knobs.env keys must be VPPT_* "
-            f"process knobs, got {sorted(bad_env)}")
-    return profile
-
-
-def apply_tuned_profile(d: dict, profile: dict) -> None:
-    """Fold a tuned profile's knobs into a raw config dict as per-key
-    DEFAULTS: a key the YAML sets explicitly always wins. Mutates
-    ``d`` in place (called by AgentConfig.from_dict before the section
-    builders, so profile keys go through exactly the same unknown-key
-    and value validation as YAML keys). The "env" section applies to
-    the process environment instead (setdefault — an exported variable
-    wins over the profile, mirroring the per-key YAML precedence)."""
-    import os
-
-    for section, vals in profile.get("knobs", {}).items():
-        if section == "env":
-            for k, v in vals.items():
-                os.environ.setdefault(str(k), str(v))
-            continue
-        raw = dict(d.get(section) or {})
-        for k, v in vals.items():
-            raw.setdefault(k, v)
-        if raw:
-            d[section] = raw
 
 
 def load_config(path: Optional[str]) -> AgentConfig:

@@ -88,7 +88,7 @@ class FleetSteering:
     steering tier and silently miss after every migration).
 
     With no ``membership``, a private in-proc kvstore backs the epoch
-    records — the single-host fleet the bench runs. Hand in a shared
+    records — a single-host fleet. Hand in a shared
     :class:`FleetMembership` to coordinate multiple steering tiers.
     """
 
@@ -290,7 +290,7 @@ class FleetSteering:
         fetched host-side (caught by ``lint.py --transfers``).
         Tenant-sliced steering (partition with tenant_ids) re-bases
         buckets per tenant; this count uses the unsliced mix and is
-        exact for the un-sliced fleets the bench and tests run."""
+        exact for un-sliced fleets."""
         import jax.numpy as jnp
 
         from vpp_tpu.ops.session import canon_mix

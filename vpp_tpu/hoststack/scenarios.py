@@ -1,12 +1,8 @@
-"""Shared session-rule scenario builders for benches and e2e tests.
+"""Session-rule scenario builders for the proxy-chain e2e test.
 
-The gen-policy-scale filler and the proxy-chain mesh seam are measured
-by ``bench.proxy_chain_bench`` AND exercised end-to-end by
-``tests/test_proxy_chain_e2e.py`` (the nginx-istio analog, reference
-tests/nginx-istio/nginx-envoy.yaml + BASELINE config #5). One
-definition keeps both harnesses measuring the SAME policy shape — a
-rule-formula change edited in one copy would silently leave bench and
-e2e on different rule sets.
+The gen-policy-scale filler and the proxy-chain mesh seam are exercised
+end-to-end by ``tests/test_proxy_chain_e2e.py`` (the nginx-istio analog,
+reference tests/nginx-istio/nginx-envoy.yaml + BASELINE config #5).
 """
 
 from __future__ import annotations

@@ -20,8 +20,7 @@ Conservation extends the steering identity downward::
 
 ``pending`` (buffered + queued) drains to zero on ``stop()``, so after
 a quiesce the end-to-end identity
-``offered == sum(delivered) + attributed drops`` holds EXACTLY —
-the live-rebalance bench asserts it packet-for-packet.
+``offered == sum(delivered) + attributed drops`` holds EXACTLY.
 """
 
 from __future__ import annotations

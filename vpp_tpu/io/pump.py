@@ -174,7 +174,7 @@ class DataplanePump:
         two): the rung ladder bounds the jit cache to log2(chain_k)
         chain shapes while a partial fold never pads more than 2× its
         real depth. 0/1 disables chaining.
-        ``fetch_delay``: fault injection for tests/bench — seconds (or
+        ``fetch_delay``: fault injection for tests — seconds (or
         ``callable(seq) -> seconds``) slept by the fetch worker before
         touching the device result, simulating a slow result transport.
         ``icmp_src_ip``: with a non-zero address (the node's pod gateway
@@ -290,8 +290,8 @@ class DataplanePump:
             # ACL table (dp.host_counters, folded per dispatch)
             "local_table_pkts": 0,
             "max_coalesce": 0, "batch_errors": 0,
-            # cumulative seconds per stage (profiling; `show io` /
-            # bench read these to attribute wire-path time). t_fetch
+            # cumulative seconds per stage (profiling; `show io` and
+            # the benchmark read these to attribute wire-path time). t_fetch
             # is the serial result COPY; t_fetch_wait is the wait for
             # the device result to become ready — time overlapped with
             # the other in-flight batches, not a serial path cost.
@@ -381,14 +381,12 @@ class DataplanePump:
             # windows exchanged, frames staged, live in-flight windows,
             # dispatched-minus-written-back windows (tx writeback lag),
             # and host callbacks made by the device program — the ring
-            # steady state makes NONE (io_callbacks stays 0; bench.py
-            # reports io_wire_callbacks_per_window from it)
+            # steady state makes NONE (io_callbacks stays 0)
             "ring_windows": 0, "ring_frames": 0, "ring_inflight": 0,
             "ring_lag": 0, "io_callbacks": 0,
         }
         # dispatch→tx latency of recent batches, seconds (experienced
-        # added latency of the device leg; ring-wait not included — the
-        # bench measures full ring-to-ring with its own timestamps).
+        # added latency of the device leg; ring-wait not included).
         # _lat_lock guards append vs snapshot: iterating a deque while
         # the tx writer appends raises RuntimeError (reachable from the
         # CLI's `show io` → latency_us()). It also guards the
@@ -2303,14 +2301,6 @@ class DataplanePump:
                 log.exception("icmp error path failed")
 
     # --- observability ---
-    def reset_latency(self) -> None:
-        """Clear the latency window so the next ``latency_us()``
-        covers only batches from here on (the bench scopes each paced
-        round this way)."""
-        with self._lat_lock:
-            self.batch_lat.clear()
-            self.pri_lat.clear()
-
     def latency_us(self) -> dict:
         """p50/p99 dispatch→tx batch latency over the recent window."""
         with self._lat_lock:

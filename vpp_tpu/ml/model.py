@@ -16,7 +16,7 @@ in tests/test_chaos.py).
 ``score_oracle`` is the host-side fixed-point reference (int64 numpy,
 bit-exact with the device kernel by shared contract — docs/ML_STAGE.md
 pins the math). tests/test_ml_stage.py carries its OWN independent
-oracle; this one serves the trainer's validation pass and the bench.
+oracle; this one serves the trainer's validation pass.
 """
 
 from __future__ import annotations

@@ -86,7 +86,7 @@ LPM_PAD = _ADDR_MAX
 # staged routes. A module constant, not a knob: the layout must be
 # recoverable from the table SHAPES alone (the kernel sees only the
 # tables pytree), and the memory cost is bounded by the caps it is
-# derived from (~4 bytes per hint row; ~2.3 MB at the 1M-route bench
+# derived from (~4 bytes per hint row; ~2.3 MB at a 1M-route
 # shape, nothing at the default 128-slot FIB).
 LPM_HINT_BITS = 16
 
@@ -102,12 +102,11 @@ LPM_HINT_MIN = 8192
 
 def lpm_hint_min() -> int:
     """The hint-engage threshold: planes at/above this capacity get a
-    stride hint table. ``VPPT_LPM_HINT_MIN`` overrides the default —
-    the autotuner's knob (tools/autotune.py sweeps it against the
-    measured hint-vs-flat crossover per backend). An env var, not a
-    config field, because the layout must be recoverable from table
-    SHAPES alone and must agree between builder staging and the
-    device kernel within one process — the VPPT_SESS_ELECTION
+    stride hint table. ``VPPT_LPM_HINT_MIN`` overrides the default
+    (tests use it to engage the hint layer at small shapes). An env
+    var, not a config field, because the layout must be recoverable
+    from table SHAPES alone and must agree between builder staging
+    and the device kernel within one process — the VPPT_SESS_ELECTION
     pattern."""
     try:
         return int(os.environ.get("VPPT_LPM_HINT_MIN", LPM_HINT_MIN))

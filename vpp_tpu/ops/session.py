@@ -65,8 +65,8 @@ whole insert resolves in ONE election round:
          to touch.)
 
      ``auto`` therefore picks sort everywhere; claim remains selectable
-     (VPPT_SESS_ELECTION=claim) as the comparison baseline and
-     ``bench.py``'s ``sess_election_*`` shoot-out re-measures both.
+     (VPPT_SESS_ELECTION=claim) as the reference the tests hold sort
+     bit-identical to.
 
 Aging is amortized: ``session_sweep`` clears a fixed stride of buckets
 per fused pipeline step (cursor threaded through the tables pytree), so
@@ -94,8 +94,8 @@ _BIG = 0x7FFFFFFF
 def election_mode(n_slots: int) -> str:
     """Trace-time election strategy (module doc). Env override first;
     ``auto`` is sort — table-size independent (claim's scatter-min
-    scales with n_slots, untenable at the 10M regime), with the bench
-    shoot-out re-validating the choice per backend each round."""
+    scales with n_slots, untenable at the 10M regime). ``claim`` stays
+    selectable as the reference the tests compare sort against."""
     mode = os.environ.get("VPPT_SESS_ELECTION", "auto")
     if mode in ("claim", "sort"):
         return mode
@@ -103,12 +103,6 @@ def election_mode(n_slots: int) -> str:
 
 from vpp_tpu.pipeline.tables import DataplaneTables
 from vpp_tpu.pipeline.vector import PacketVector
-
-# Legacy linear-probe depth — kept ONLY for the bench's old-vs-new
-# baseline (``hashmap_insert_linear``); the set-associative table's
-# probe window is the bucket's way count, carried in the array shape.
-SESS_PROBES = 4
-
 
 def _hash_mix(src: jnp.ndarray, dst: jnp.ndarray, ports: jnp.ndarray,
               proto: jnp.ndarray) -> jnp.ndarray:
@@ -439,31 +433,6 @@ def session_touch(
         sess_time=tables.sess_time.at[widx // ways, widx % ways].set(
             now, mode="drop")
     )
-
-
-def _elect(cand: jnp.ndarray, slot: jnp.ndarray, n_slots: int) -> jnp.ndarray:
-    """One election round: among candidate packets contending for the
-    same flat slot id, the lowest packet index wins. Strategy ladder in
-    the module doc (claim scatter-min vs stable sort) — semantics are
-    identical by construction, picked at trace time. Used by the
-    legacy linear-probe baseline; the set-associative insert uses the
-    ranked form (``_elect_rank``)."""
-    batch = slot.shape[0]
-    p_idx = jnp.arange(batch, dtype=jnp.int32)
-    # jax-ok: n_slots is a shape-derived Python int — election_mode is a
-    # trace-time strategy pick, not a tracer branch
-    if election_mode(n_slots) == "claim":
-        claim = jnp.full((n_slots,), _BIG, dtype=jnp.int32)
-        claim = claim.at[jnp.where(cand, slot, n_slots)].min(
-            p_idx, mode="drop")
-        return cand & (claim[slot] == p_idx)
-    slot_key = jnp.where(cand, slot, n_slots)  # non-cands sort last
-    order = jnp.argsort(slot_key)               # stable (jnp default)
-    ss = slot_key[order]
-    first_of_run = jnp.concatenate(
-        [jnp.ones((1,), bool), ss[1:] != ss[:-1]])
-    return jnp.zeros(batch, bool).at[order].set(
-        first_of_run & (ss < n_slots))
 
 
 def _bucket_reps(h: jnp.ndarray, pending: jnp.ndarray, n_buckets: int,
@@ -905,66 +874,6 @@ def _session_expire_impl(tables: DataplaneTables, now, max_age) -> DataplaneTabl
 # bulk pass a single device call (now/max_age are traced scalars, so
 # differing values never retrace).
 session_expire = jax.jit(_session_expire_impl)
-
-
-# --- legacy linear-probe baseline (bench comparison ONLY) ------------
-
-
-def hashmap_insert_linear(
-    valid: jnp.ndarray,
-    time: jnp.ndarray,
-    keys: Tuple[jnp.ndarray, ...],
-    key_vals: Tuple[jnp.ndarray, ...],
-    h: jnp.ndarray,
-    want: jnp.ndarray,
-    now: jnp.ndarray,
-    probes: int = SESS_PROBES,
-    max_age=None,
-) -> tuple:
-    """The pre-rework open-addressing insert (linear probing, one
-    election + full scatter round PER PROBE), kept verbatim-in-spirit
-    as the ``sess_insert_ns_pkt`` old-vs-new bench baseline
-    (bench.py session_scale_bench). FLAT [n_slots] arrays. Not used by
-    the pipeline."""
-    n_slots = valid.shape[0]
-    keys = tuple(keys)
-
-    def live_at(idx):
-        l = valid[idx] == 1
-        if max_age is not None:
-            l = l & (now - time[idx] <= max_age)
-        return l
-
-    def key_at(idx):
-        same = live_at(idx)
-        for arr, val in zip(keys, key_vals):
-            same = same & (arr[idx] == val)
-        return same
-
-    exists = jnp.zeros_like(want)
-    exist_idx = jnp.zeros_like(h)
-    for p in range(probes):
-        idx = (h + p) & (n_slots - 1)
-        same = key_at(idx)
-        exist_idx = jnp.where(same & ~exists, idx, exist_idx)
-        exists = exists | same
-    refresh = want & exists
-    time = time.at[jnp.where(refresh, exist_idx, n_slots)].set(
-        now, mode="drop")
-    pending = want & ~exists
-    for p in range(probes):
-        idx = (h + p) & (n_slots - 1)
-        cand = pending & ~live_at(idx)
-        winner = _elect(cand, idx, n_slots)
-        widx = jnp.where(winner, idx, n_slots)
-        keys = tuple(
-            arr.at[widx].set(val, mode="drop")
-            for arr, val in zip(keys, key_vals)
-        )
-        valid = valid.at[widx].set(1, mode="drop")
-        time = time.at[widx].set(now, mode="drop")
-        pending = pending & ~key_at(idx)
-    return valid, time, keys, pending
 
 
 # --- pallas rung (ISSUE 16) -------------------------------------------

@@ -2,7 +2,7 @@
 
 nanoPU (PAPERS.md) argues the metric that matters for reflex workloads
 is wire-to-wire TAIL latency, yet until this round the only latency
-numbers were host-side medians sampled around whole bench sections —
+numbers were host-side medians sampled around whole benchmark runs —
 nothing per-packet, nothing under load, nothing a latency governor
 (ROADMAP item 3) could close a loop on. This module puts the
 measurement substrate INSIDE the fused step:
@@ -100,7 +100,7 @@ def lat_bucket(lat_us: jnp.ndarray, n_buckets: int) -> jnp.ndarray:
 
 def lat_bucket_np(lat_us: np.ndarray, n_buckets: int) -> np.ndarray:
     """The independent host-side twin of ``lat_bucket`` (differential
-    tests + the bench's host recompute)."""
+    tests)."""
     thresholds = np.asarray([1 << k for k in range(1, n_buckets)],
                             np.int64)
     return (np.asarray(lat_us, np.int64)[:, None]

@@ -624,7 +624,7 @@ class ClusterDataplane:
     @property
     def classifier_impl(self) -> str:
         """The rule-sharded global classify the LIVE cluster epoch runs
-        ("dense" | "mxu" | "bv") — `show partitions` / bench keys."""
+        ("dense" | "mxu" | "bv") — `show partitions`."""
         return self._impl
 
     @property

@@ -374,7 +374,7 @@ def _ring_call(step, slots: int, tel: str = "off"):
 # (keyed by the selection gates + call form): make_pipeline_step is
 # memoized so the underlying function identity is stable, and sharing
 # the jit wrappers too means N dataplanes in one process (tests, the
-# bench, multi-instance agents) compile each variant once.
+# benchmark, multi-instance agents) compile each variant once.
 _JIT_STEPS: Dict[tuple, object] = {}
 
 # Runtime jit-compile guard (ISSUE 5 tentpole): every TRACE of a step
@@ -518,10 +518,9 @@ def jit_compile_budget(budget: int) -> _JitBudget:
 # keyed by site. Exported as
 # ``vpp_tpu_device_transfer_bytes_total{site=}`` (stats/collector.py),
 # shown by `show io`, enforced per-test by the opt-in transfer_budget
-# fixture (tests/conftest.py), and recorded per bench section — the
-# wire/persistent sections must fetch rider/descriptor bytes per
-# window, never table columns ("~270 MB crosses the transport" was the
-# PR-6/8/12 regression class).
+# fixture (tests/conftest.py) — the wire/persistent paths must fetch
+# rider/descriptor bytes per window, never table columns ("~270 MB
+# crosses the transport" was the PR-6/8/12 regression class).
 _TRANSFER_BYTES: Dict[str, int] = {}
 _TRANSFER_LOCK = threading.Lock()
 
@@ -852,12 +851,6 @@ class Dataplane:
         # re-shipped FIB state (vpp_tpu_fib_churn_commit_seconds)
         self.fib_churn_hist = None
         self._refresh_selection()
-        # diagnostic classify-probe accumulators (time_classifier):
-        # exported as the stage="classify" row of the
-        # vpp_tpu_pump_stage_seconds family and shown by `show acl`
-        self.classify_seconds = 0.0
-        self.classify_ns_pkt: Optional[float] = None
-        self._classify_probe_cache: Dict[str, object] = {}
         # cumulative host seconds inside process_packed[_chain]: turning
         # the host arguments into device arrays (t_dp_upload) and the
         # jitted step call until it returns (t_dp_call; dispatch is
@@ -1405,42 +1398,6 @@ class Dataplane:
                             sess_impl=self._session_impl,
                             sess_hash=self._sess_hash,
                             overlay=self._overlay)
-
-    def time_classifier(self, batch: int = 256, iters: int = 10) -> float:
-        """Diagnostic: time the SELECTED global classifier in isolation
-        over a synthetic batch and return ns/packet. Accumulates wall
-        seconds into ``classify_seconds`` (exported as the
-        stage="classify" row of ``vpp_tpu_pump_stage_seconds``) and
-        records ``classify_ns_pkt`` for `show acl`. Not hot-path work —
-        the first call per impl pays a jit compile; bench/operator use."""
-        from vpp_tpu.pipeline.graph import _classifier_fns
-        from vpp_tpu.pipeline.vector import make_packet_vector
-
-        with self._lock:
-            if self.tables is None:
-                raise RuntimeError("no live tables to time against")
-            tables = self.tables
-            impl = self._classifier_impl
-        fn = self._classify_probe_cache.get(impl)
-        if fn is None:
-            fn = jax.jit(_classifier_fns(impl)[0])
-            self._classify_probe_cache[impl] = fn
-        uplink = self.uplink_if if self.uplink_if is not None else 0
-        pkts = make_packet_vector(
-            [{"src": "172.16.0.9", "dst": "10.1.1.2", "proto": 6,
-              "sport": 40000 + i, "dport": 8000 + (i % 20),
-              "rx_if": uplink} for i in range(min(batch, 64))],
-            n=batch,
-        )
-        jax.block_until_ready(fn(tables, pkts).permit)  # compile+warm
-        t0 = _time.perf_counter()
-        for _ in range(iters):
-            out = fn(tables, pkts)
-        jax.block_until_ready(out.permit)
-        dt = _time.perf_counter() - t0
-        self.classify_seconds += dt
-        self.classify_ns_pkt = dt / iters / batch * 1e9
-        return self.classify_ns_pkt
 
     # --- traffic ---
     def _pick_step(self):

@@ -899,8 +899,8 @@ def pipeline_step_fast(
     Bit-exact with ``pipeline_step`` ONLY when every valid packet hits
     a live reflective session and none DNAT-matches — the invariant
     ``pipeline_step_auto``'s dispatch predicate guarantees. Exposed on
-    its own for the differential test and the bench's speedup capture;
-    production traffic goes through the auto dispatcher.
+    its own for the differential test; production traffic goes through
+    the auto dispatcher.
     """
     # jax-ok: overlay is a trace-time-static step-factory gate (a
     # Python string baked into the jit key), not a tracer branch

@@ -37,7 +37,7 @@ paid a fresh per-instance jit every restart.
 ``stats["io_callbacks"]`` counts host callback invocations made by the
 device program. The ring design makes none — the counter exists so a
 regression reintroducing a callback into the steady state is a
-measured fact (`io_wire_callbacks_per_window` in bench.py), not prose.
+measured fact, not prose.
 """
 
 from __future__ import annotations

@@ -946,8 +946,8 @@ _UPLOAD_GROUPS: Dict[str, Tuple[str, ...]] = {
                "glb_ml_tnt_mode", "glb_ml_tnt_thresh", "tnt_vni"),
     # service NAT44 LB planes (ISSUE 19): their OWN group so a rolling
     # backend replacement ships ONLY svc bytes — every other group
-    # keeps its cached device-array identity (the zero-reship
-    # acceptance bench pins). Additionally rides the incremental
+    # keeps its cached device-array identity (zero reship).
+    # Additionally rides the incremental
     # scatter-blob path (_upload_svc): changed VIP rows confine to a
     # block and ship as one few-KB blob.
     "svc": ("svc_vip_ip", "svc_vip_port", "svc_vip_proto",

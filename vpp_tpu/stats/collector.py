@@ -421,8 +421,7 @@ TENANT_PLANE_GAUGES = (
     ("vpp_tpu_tenant_rx_packets",
      "packets received per tenant (device accounting plane)"),
     ("vpp_tpu_tenant_goodput_packets",
-     "packets forwarded per tenant (the isolation bench's goodput "
-     "axis)"),
+     "packets forwarded per tenant (goodput)"),
     ("vpp_tpu_tenant_rl_dropped_packets",
      "per-tenant token-bucket rate-limit drops (tenant_quota)"),
     ("vpp_tpu_tenant_quota_fail_packets",
@@ -1039,8 +1038,8 @@ class StatsCollector:
         """Attach the MlModelSource (vpp_tpu/ml/loader.py) so
         publish() exports load outcomes and the ml degraded
         component. The stage/version gauges publish from the
-        dataplane regardless — in-process model staging (tests, the
-        bench) is visible without a loader."""
+        dataplane regardless — in-process model staging (tests) is
+        visible without a loader."""
         self._ml_source = source
 
     def set_vcl(self, server) -> None:
@@ -1473,14 +1472,6 @@ class StatsCollector:
             for outcome, n in s["restores"].items():
                 self.snapshot_restore_gauge.set(
                     float(n), outcome=outcome)
-        # classify-stage occupancy in the pump stage family: cumulative
-        # seconds of the isolated classify probe
-        # (Dataplane.time_classifier — the bench and operators drive
-        # it; 0 until the first probe). Dataplane-owned, so published
-        # even without a pump attached.
-        self.pump_stage_gauge.set(
-            float(getattr(self.dp, "classify_seconds", 0.0)),
-            stage="classify")
         pump = self.pump
         # the drops-by-cause family publishes whenever EITHER source
         # exists: a mesh-mode agent attaches only the daemon stats
